@@ -4,7 +4,10 @@ Runs the §4 hot path — ``repro.core.pipeline`` via ``launch/train.py
 --strategy pipeline`` — over the schedule x wire-codec grid on a small
 dense config, in subprocesses (the stage count needs
 ``--xla_force_host_platform_device_count`` set *before* jax initialises,
-which an already-running bench harness cannot do).
+which an already-running bench harness cannot do).  The forced host
+devices are CPU devices: every child runs with ``JAX_PLATFORMS=cpu``, and
+every record says ``"platform": "cpu"`` — these rows time XLA's CPU
+backend, never a chip.
 
 The artifact records, per benchmark: us/step, final loss after the fixed
 step budget, on-wire bytes per boundary hop (int8 scales accounted), the
@@ -43,7 +46,7 @@ def artifact_path() -> str:
         else ARTIFACT
 
 SCHEMA_KEYS = {"schema", "arch", "config", "benchmarks", "derived"}
-BENCH_KEYS = {"name", "schedule", "virtual_stages", "wire_codec",
+BENCH_KEYS = {"name", "platform", "schedule", "virtual_stages", "wire_codec",
               "us_per_step", "final_loss", "wire_bytes_per_hop",
               "bubble_fraction", "peak_stash_bytes", "stash_codes",
               "grad_ring_codes", "loop_length"}
@@ -56,6 +59,7 @@ def _scenario(name: str, schedule: str, codec: str, cfg: dict,
         metrics_path = f.name
     env = dict(
         os.environ,
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count="
                   f"{cfg['n_stages']}",
         PYTHONPATH=os.path.join(ROOT, "src"),
@@ -87,6 +91,7 @@ def _scenario(name: str, schedule: str, codec: str, cfg: dict,
     stats, final = records[0], records[-1]
     return {
         "name": name,
+        "platform": "cpu",
         "schedule": schedule,
         "virtual_stages": stats.get("virtual_stages", 1),
         "wire_codec": codec,
@@ -224,6 +229,7 @@ def validate_artifact(path: str | None = None) -> dict:
     for rec in art["benchmarks"]:
         miss = BENCH_KEYS - set(rec)
         assert not miss, f"benchmark {rec.get('name')} missing {miss}"
+        assert rec["platform"] == "cpu", rec["platform"]
     return art
 
 

@@ -39,7 +39,12 @@ _SRC = os.path.abspath(
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
+# The modules that spawn CPU-pinned JAX children (forced host devices) run
+# before any module that runs JAX in this process: on a TPU host that
+# process then holds the chip, and a chip belongs to one process.
 MODULES = [
+    "benchmarks.bench_pipeline",
+    "benchmarks.bench_roofline",
     "benchmarks.bench_convergence",
     "benchmarks.bench_butterfly",
     "benchmarks.bench_clasp",
@@ -47,8 +52,6 @@ MODULES = [
     "benchmarks.bench_codecs",
     "benchmarks.bench_swarm",
     "benchmarks.bench_kernels",
-    "benchmarks.bench_pipeline",
-    "benchmarks.bench_roofline",
     "benchmarks.bench_chaos",
     "benchmarks.bench_serve",
 ]

@@ -21,9 +21,8 @@ assertion only cares whether the count is zero.  Regions may be entered
 repeatedly; counts accumulate under the same label.
 
 Listeners are process-global in jax, so ``TraceWatch`` is a context
-manager that unregisters on exit (via the private-but-stable
-``jax._src.monitoring`` hook; ``clear_event_listeners`` would nuke other
-listeners).  Events raised outside any active region are accumulated
+manager that unregisters its own listener on exit
+(``clear_event_listeners`` would nuke other listeners).  Events raised outside any active region are accumulated
 under the ``(unlabeled)`` pseudo-region rather than dropped.
 """
 from __future__ import annotations
@@ -64,9 +63,8 @@ class TraceWatch:
 
     def __exit__(self, *exc) -> None:
         if self._registered:
-            from jax._src import monitoring as _monitoring
-            _monitoring._unregister_event_duration_listener_by_callback(
-                self._callback)
+            import jax.monitoring
+            jax.monitoring.unregister_event_duration_listener(self._callback)
             self._registered = False
 
     # -- regions -----------------------------------------------------------

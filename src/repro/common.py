@@ -17,28 +17,6 @@ import numpy as np
 PyTree = Any
 
 # ---------------------------------------------------------------------------
-# shard_map compat
-# ---------------------------------------------------------------------------
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    _shard_map_impl = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def shard_map_unchecked(body, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across the jax API
-    rename: ``check_vma`` (new) vs ``check_rep`` (<= 0.4.x).  All our
-    bodies use ppermute/psum manually, so the check stays disabled."""
-    try:
-        return _shard_map_impl(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_vma=False)
-    except TypeError:
-        return _shard_map_impl(body, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, check_rep=False)
-
-
-# ---------------------------------------------------------------------------
 # Pytree helpers
 # ---------------------------------------------------------------------------
 

@@ -41,14 +41,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common import cdiv, shard_map_unchecked
+from repro.common import cdiv
 from repro.core import compression
 from repro.kernels import ops
-
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 # ---------------------------------------------------------------------------
@@ -519,7 +514,8 @@ def butterfly_all_reduce_mesh(x: jax.Array, axis: str, mesh,
         merged = merged[:size].reshape(v.shape)
         return merged, agree
 
-    return shard_map_unchecked(
-        body, mesh, (in_spec,),
-        (in_spec, jax.sharding.PartitionSpec()),
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(in_spec,),
+        out_specs=(in_spec, jax.sharding.PartitionSpec()), check_vma=False,
     )(x)

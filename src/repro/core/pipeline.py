@@ -93,8 +93,6 @@ from repro.models.layers import (
 from repro.models.layers import embed as embed_fn
 from repro.models.layers import logits as logits_fn
 
-from repro.common import shard_map_unchecked as _shard_map
-
 
 SCHEDULES = ("gpipe", "1f1b", "interleaved", "zerobubble", "decode")
 WIRE_CODECS = ("none", "int8")
@@ -555,6 +553,18 @@ def init_pipeline_params(key, cfg: ModelConfig, spec: PipelineSpec) -> dict:
     return params
 
 
+def pipeline_param_shardings(params, mesh) -> dict:
+    """Mesh layout of an ``init_pipeline_params`` tree (arrays or shapes):
+    every ``stages`` leaf splits its leading stage axis over ``model`` —
+    the layout the step's shard_map consumes — and the embeddings and
+    final norm are replicated."""
+    from jax.sharding import NamedSharding
+    stage, rep = NamedSharding(mesh, P("model")), NamedSharding(mesh, P())
+    out = jax.tree.map(lambda _: rep, params)
+    out["stages"] = jax.tree.map(lambda _: stage, params["stages"])
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Boundary codecs (fused Pallas hot path, jnp fallback kept as oracle path)
 # ---------------------------------------------------------------------------
@@ -688,10 +698,10 @@ def pipeline_apply(params, x_micro, cfg: ModelConfig, spec: PipelineSpec,
         return outputs
 
     stage_specs = jax.tree.map(lambda _: P("model"), params["stages"])
-    return _shard_map(
-        body, mesh,
-        (P(None, batch_axes, None, None), stage_specs),
-        P(None, batch_axes, None, None),
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(None, batch_axes, None, None), stage_specs),
+        out_specs=P(None, batch_axes, None, None), check_vma=False,
     )(x_micro, params["stages"])
 
 
@@ -918,11 +928,11 @@ def pipeline_loss_fused(params, batch, cfg: ModelConfig, spec: PipelineSpec,
 
     stage_specs = jax.tree.map(lambda _: P("model"), params["stages"])
     unembed = params["embeds"].get("unembed", params["embeds"]["embed"])
-    return _shard_map(
-        body, mesh,
-        (P(None, batch_axes, None), P(None, batch_axes, None),
-         P(None, None), P(None, None), P(None), stage_specs),
-        P(),
+    return jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(None, batch_axes, None), P(None, batch_axes, None),
+                  P(None, None), P(None, None), P(None), stage_specs),
+        out_specs=P(), check_vma=False,
     )(tokens_m, labels_m, params["embeds"]["embed"], unembed,
       params["final_norm"], params["stages"])
 
@@ -1228,11 +1238,11 @@ def pipeline_timetable_grads(params, batch, cfg: ModelConfig,
     stage_specs = jax.tree.map(lambda _: P("model"), params["stages"])
     tied = "unembed" not in params["embeds"]
     unembed = params["embeds"].get("unembed", params["embeds"]["embed"])
-    loss, g_stages, g_emb, g_unemb, g_fg = _shard_map(
-        body, mesh,
-        (P(None, batch_axes, None), P(None, batch_axes, None),
-         P(None, None), P(None, None), P(None), stage_specs),
-        (P(), stage_specs, P(), P(), P()),
+    loss, g_stages, g_emb, g_unemb, g_fg = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(None, batch_axes, None), P(None, batch_axes, None),
+                  P(None, None), P(None, None), P(None), stage_specs),
+        out_specs=(P(), stage_specs, P(), P(), P()), check_vma=False,
     )(tokens_m, labels_m, params["embeds"]["embed"], unembed,
       params["final_norm"], params["stages"])
 

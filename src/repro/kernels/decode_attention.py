@@ -13,8 +13,10 @@ the prefix mask (``kpos < kv_len``) apply, exactly ``ref.attention``'s
 semantics with ``causal=True`` and a ``kv_len``.
 
 ``kv_len``/``q_offset`` are traced per-batch scalars (they ride the KV
-cache state through jit), shipped to the kernel as one (B, 2) int32 SMEM
-operand — scalars steer control flow, so they must live in SMEM, not VMEM.
+cache state through jit), shipped to the kernel as one (B, 2) int32
+scalar-prefetch operand — scalars steer control flow, so they must live in
+SMEM, and a whole-array prefetch sidesteps the (8, 128) block rule that a
+per-lane (1, 2) SMEM block would break.
 
 Inference-only: no ``custom_vjp`` — the serve plane never differentiates,
 and ``ops.flash_attention`` routes autodiff-bearing shapes (no cache) to
@@ -28,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import cdiv
 
@@ -45,8 +48,9 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    kv_len = meta_ref[0, 0]
-    q_off = meta_ref[0, 1]
+    b = pl.program_id(0)
+    kv_len = meta_ref[b, 0]
+    q_off = meta_ref[b, 1]
     kv_lo = j * bkv
 
     # blocks entirely past the valid prefix contribute nothing
@@ -54,7 +58,11 @@ def _decode_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, D)
         k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        # a cache buffer that is not a whole number of kv blocks leaves the
+        # tail of its last block unwritten: zero those rows so p @ v never
+        # multiplies a zero weight by whatever bits sit there
+        vpos = kv_lo + jax.lax.broadcasted_iota(jnp.int32, (bkv, 1), 0)
+        v = jnp.where(vpos < kv_len, v_ref[0, 0].astype(jnp.float32), 0.0)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         qpos = q_off + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0)
         kpos = kv_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
@@ -99,41 +107,27 @@ def decode_attention(q, k, v, *, q_offset, kv_len, softmax_scale=None,
     n_kv = cdiv(Skv, bkv)
     grid = (B, H, n_kv)
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        smem = pl.BlockSpec((1, 2), lambda b, h, j: (b, 0),
-                            memory_space=pltpu.SMEM)
-        scratch = [pltpu.VMEM((Sq,), jnp.float32),
-                   pltpu.VMEM((Sq,), jnp.float32),
-                   pltpu.VMEM((Sq, D), jnp.float32)]
-        cp_cls = getattr(pltpu, "CompilerParams", None) \
-            or getattr(pltpu, "TPUCompilerParams", None)
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "parallel",
-                                 "arbitrary")) if cp_cls else None
-    except ImportError:  # pragma: no cover
-        from repro.kernels import ref
-        return ref.attention(q, k, v, causal=True, q_offset=q_offset,
-                             kv_len=kv_len, softmax_scale=scale)
-
-    kwargs = {}
-    if compiler_params is not None and not interpret:
-        kwargs["compiler_params"] = compiler_params
-
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((1, 1, Sq, D), lambda b, h, j, m: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j, m: (b, h // G, j, 0)),
+            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j, m: (b, h // G, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, Sq, D),
+                               lambda b, h, j, m: (b, h, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((Sq,), jnp.float32),
+                        pltpu.VMEM((Sq,), jnp.float32),
+                        pltpu.VMEM((Sq, D), jnp.float32)],
+    )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, bq=Sq, bkv=bkv,
                           n_kv=n_kv),
-        grid=grid,
-        in_specs=[
-            smem,
-            pl.BlockSpec((1, 1, Sq, D), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j: (b, h // G, j, 0)),
-            pl.BlockSpec((1, 1, bkv, D), lambda b, h, j: (b, h // G, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Sq, D), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(meta, qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import cdiv
 from repro.kernels import ref
@@ -84,24 +85,6 @@ def _flash_call(q, k, v, *, causal, q_offset, scale, interpret,
     n_kv = cdiv(Skv, bkv)
     grid = (B, H, cdiv(Sq, bq), n_kv)
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        scratch = [pltpu.VMEM((bq,), jnp.float32),
-                   pltpu.VMEM((bq,), jnp.float32),
-                   pltpu.VMEM((bq, D), jnp.float32)]
-        # CompilerParams (new jax) vs TPUCompilerParams (<= 0.4.x)
-        cp_cls = getattr(pltpu, "CompilerParams", None) \
-            or getattr(pltpu, "TPUCompilerParams", None)
-        compiler_params = cp_cls(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")) if cp_cls else None
-    except ImportError:  # pragma: no cover
-        scratch, compiler_params = [], None
-
-    kwargs = {}
-    if compiler_params is not None and not interpret:
-        kwargs["compiler_params"] = compiler_params
-
     return pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           q_offset=q_offset, bq=bq, bkv=bkv, n_kv=n_kv),
@@ -113,9 +96,13 @@ def _flash_call(q, k, v, *, causal, q_offset, scale, interpret,
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
+                        pltpu.VMEM((bq,), jnp.float32),
+                        pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(q, k, v)
 
 

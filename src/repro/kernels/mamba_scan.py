@@ -24,6 +24,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import cdiv
 
@@ -77,18 +78,6 @@ def mamba_scan(delta, x, b_ssm, c_ssm, a, *, interpret: bool = False,
     bs_ = min(bs, S)
     grid = (B, cdiv(d_in, bd_), cdiv(S, bs_))
 
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        scratch = [pltpu.VMEM((bd_, ds), jnp.float32)]
-        kwargs = {}
-        cp_cls = getattr(pltpu, "CompilerParams", None) \
-            or getattr(pltpu, "TPUCompilerParams", None)
-        if not interpret and cp_cls:
-            kwargs["compiler_params"] = cp_cls(
-                dimension_semantics=("parallel", "parallel", "arbitrary"))
-    except ImportError:  # pragma: no cover
-        scratch, kwargs = [], {}
-
     return pl.pallas_call(
         functools.partial(_mamba_kernel, bs=bs_, bd=bd_, ds=ds),
         grid=grid,
@@ -101,9 +90,10 @@ def mamba_scan(delta, x, b_ssm, c_ssm, a, *, interpret: bool = False,
         ],
         out_specs=pl.BlockSpec((1, bs_, bd_), lambda i, j, s: (i, s, j)),
         out_shape=jax.ShapeDtypeStruct((B, S, d_in), jnp.float32),
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.VMEM((bd_, ds), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        **kwargs,
     )(delta, x, b_ssm, c_ssm, a)
 
 

@@ -1,10 +1,13 @@
 """Public jit'd entry points for the Pallas kernels.
 
 Dispatch policy:
-  * On TPU backends the Pallas kernels run compiled.
-  * Everywhere else (this CPU container, unit tests) we run the pure-jnp
-    reference oracle — unless ``REPRO_FORCE_PALLAS_INTERPRET=1``, which runs
-    the actual kernel bodies under ``interpret=True`` (used by kernel tests).
+  * On a TPU backend the Pallas kernels run compiled.  There is no fallback:
+    a kernel the chip's compiler refuses is an error, never a quiet switch
+    to the reference.
+  * On a non-TPU backend (CPU runs and tests) the pure-jnp reference in
+    ``kernels/ref.py`` runs — unless ``REPRO_FORCE_PALLAS_INTERPRET=1``,
+    which runs the actual kernel bodies under ``interpret=True`` (used by
+    kernel tests).
 
 Models call ONLY these wrappers, never the kernels directly.
 """
@@ -20,12 +23,8 @@ from repro.kernels import ref
 
 
 def _use_pallas() -> bool:
-    if os.environ.get("REPRO_FORCE_PALLAS_INTERPRET") == "1":
-        return True
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # backend not initialised yet
-        return False
+    return (jax.default_backend() == "tpu"
+            or os.environ.get("REPRO_FORCE_PALLAS_INTERPRET") == "1")
 
 
 def _interpret() -> bool:
@@ -211,10 +210,7 @@ def shard_merge(shards, valid):
 # ---------------------------------------------------------------------------
 
 
-import functools as _functools
-
-
-@_functools.lru_cache(maxsize=None)
+@functools.lru_cache(maxsize=None)
 def _mamba_scan_fn(interpret: bool):
     from repro.kernels import mamba_scan as ms
 

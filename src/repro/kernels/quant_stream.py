@@ -3,7 +3,8 @@
 Weights/optimizer deltas are quantized on the way into the StateStore
 (paper §2 stage 2).  Symmetric per-block int8: each 256-element block gets
 one fp32 scale (amax/127).  The kernels tile the flat vector into
-(rows x 256) panels so quantize+scale extraction happen in one VMEM pass.
+lane-dense (rows x 128) panels so quantize+scale extraction happen in one
+VMEM pass, with no relayout of the vector around the kernel.
 Not differentiated (codec runs outside the autodiff graph).
 """
 from __future__ import annotations
@@ -13,18 +14,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.common import cdiv
 
 BLOCK = 256
-ROWS_PER_STEP = 512          # 512 x 256 fp32 = 512 KiB per VMEM panel
+LANES = 128
+# Scales per grid step.  They leave as a 1-D block, which XLA tiles in
+# units of 1024: a smaller block is refused on TPU once the vector spans
+# more than one step.  At BLOCK = 256 a step reads a 1 MiB fp32 panel.
+BLOCKS_PER_STEP = 1024
 
 
-def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)                   # (rows, BLOCK)
-    amax = jnp.max(jnp.abs(x), axis=1, keepdims=True)
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(x / scale), -127, 127)
+def _view(n: int, block: int) -> tuple[int, int]:
+    """(width, rows-per-block) of the 2-D view a codec kernel tiles.
+
+    A block that is a whole number of 128-lane rows is viewed lane-dense,
+    as (n / 128, 128): that view and XLA's tiled layout of the flat vector
+    are the same bytes, so neither the fp32 input nor the int8 codes are
+    relaid out around the kernel.  Block b then spans b / 128 consecutive
+    rows, which the kernel gathers with stride-b/128 loads.  Any other
+    block (short wire-code rows) is viewed as (n / block, block)."""
+    width = LANES if block % LANES == 0 else block
+    return width, block // width
+
+
+def _row_scales(scale, k: int, scr):
+    """Repeat each block's (rp, 1) scale over its k view rows, into scr."""
+    rp = scale.shape[0]
+    wide = jnp.broadcast_to(scale, (rp, scr.shape[1]))
+    for p in range(k):
+        scr[pl.ds(p, rp, stride=k), :] = wide
+    return scr[...]
+
+
+def _quant_kernel(x_ref, q_ref, s_ref, scr, *, k: int):
+    rp = s_ref.shape[0]
+    amax = None
+    for p in range(k):
+        part = x_ref[pl.ds(p, rp, stride=k), :].astype(jnp.float32)
+        m = jnp.max(jnp.abs(part), axis=1, keepdims=True)
+        amax = m if amax is None else jnp.maximum(amax, m)
+    scale = jnp.where(amax > 0, amax / 127.0, 1.0)              # (rp, 1)
+    x = x_ref[...].astype(jnp.float32)
+    q = jnp.clip(jnp.round(x / _row_scales(scale, k, scr)), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
     s_ref[...] = scale[:, 0]
 
@@ -32,40 +65,43 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 def quantize_int8(x, block: int = BLOCK, interpret: bool = False):
     (n,) = x.shape
     assert n % block == 0, (n, block)
-    rows = n // block
-    rp = min(ROWS_PER_STEP, rows)
-    x2d = x.reshape(rows, block)
+    width, k = _view(n, block)
+    n_blocks = n // block
+    rp = min(BLOCKS_PER_STEP, n_blocks)
     q, s = pl.pallas_call(
-        _quant_kernel,
-        grid=(cdiv(rows, rp),),
-        in_specs=[pl.BlockSpec((rp, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((rp, block), lambda i: (i, 0)),
+        functools.partial(_quant_kernel, k=k),
+        grid=(cdiv(n_blocks, rp),),
+        in_specs=[pl.BlockSpec((k * rp, width), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((k * rp, width), lambda i: (i, 0)),
                    pl.BlockSpec((rp,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((rows, block), jnp.int8),
-                   jax.ShapeDtypeStruct((rows,), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((n // width, width), jnp.int8),
+                   jax.ShapeDtypeStruct((n_blocks,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((k * rp, width), jnp.float32)],
         interpret=interpret,
-    )(x2d)
+    )(x.reshape(n // width, width))
     return q.reshape(n), s
 
 
-def _dequant_kernel(q_ref, s_ref, o_ref):
-    q = q_ref[...].astype(jnp.float32)
-    o_ref[...] = q * s_ref[...][:, None]
+def _dequant_kernel(q_ref, s_ref, o_ref, scr, *, k: int):
+    scale = s_ref[...][:, None]                                 # (rp, 1)
+    o_ref[...] = q_ref[...].astype(jnp.float32) * _row_scales(scale, k, scr)
 
 
 def dequantize_int8(q, scales, block: int = BLOCK, interpret: bool = False):
     (n,) = q.shape
-    rows = n // block
-    rp = min(ROWS_PER_STEP, rows)
+    width, k = _view(n, block)
+    n_blocks = n // block
+    rp = min(BLOCKS_PER_STEP, n_blocks)
     out = pl.pallas_call(
-        _dequant_kernel,
-        grid=(cdiv(rows, rp),),
-        in_specs=[pl.BlockSpec((rp, block), lambda i: (i, 0)),
+        functools.partial(_dequant_kernel, k=k),
+        grid=(cdiv(n_blocks, rp),),
+        in_specs=[pl.BlockSpec((k * rp, width), lambda i: (i, 0)),
                   pl.BlockSpec((rp,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((rp, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, block), jnp.float32),
+        out_specs=pl.BlockSpec((k * rp, width), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // width, width), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((k * rp, width), jnp.float32)],
         interpret=interpret,
-    )(q.reshape(rows, block), scales)
+    )(q.reshape(n // width, width), scales)
     return out.reshape(n)
 
 

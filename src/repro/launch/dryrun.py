@@ -1,10 +1,15 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-THE two lines above must execute before any other import (jax locks the
-device count at first init).  This module proves the distribution config is
+THE lines above must execute before any other import (jax locks the
+device count at first init).  The 512 forced host devices are CPU
+devices, so the dry-run pins itself to the CPU and appends its flag to
+any ``XLA_FLAGS`` already set.  Never import this module into a process
+that is meant to use a chip.  This module proves the distribution config is
 coherent without hardware: ``jax.jit(step).lower(**specs).compile()`` must
 succeed for the 16x16 single-pod mesh and the 2x16x16 multi-pod mesh for
 every assigned architecture and input shape, and the compiled artifact

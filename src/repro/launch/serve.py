@@ -38,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 
 
@@ -248,6 +249,7 @@ def main(argv=None):
                     help="skip the greedy-parity check against the "
                          "sequential oracle (swarm mode, temperature 0)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.smoke:

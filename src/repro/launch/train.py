@@ -30,6 +30,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import time
@@ -42,6 +43,7 @@ from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.core.pipeline import SCHEDULES
 from repro.data.pipeline import DataConfig, SyntheticCorpus
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 
 
@@ -82,6 +84,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--lr", type=float, default=0.1,
                     help="SGD lr for the pipeline strategy loop")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = configs.get(args.arch)
     if args.smoke:
@@ -155,6 +158,7 @@ def _pipeline_main(args, cfg) -> dict:
         PipelineSpec,
         init_pipeline_params,
         pipeline_loss_and_grads,
+        pipeline_param_shardings,
         schedule_stats,
     )
     assert not (args.ckpt_dir or args.resume
@@ -189,9 +193,21 @@ def _pipeline_main(args, cfg) -> dict:
     corpus = SyntheticCorpus(DataConfig(
         vocab_size=mcfg.vocab_size, seq_len=args.seq_len,
         batch_size=args.batch_size, seed=args.seed))
-    params = init_pipeline_params(jax.random.key(args.seed), mcfg, spec)
+    # built under jit straight into its mesh layout: each device receives
+    # its own stage slice, and no device ever holds the whole model
+    init = functools.partial(init_pipeline_params, cfg=mcfg, spec=spec)
+    key = jax.random.key(args.seed)
+    params = jax.jit(init, out_shardings=pipeline_param_shardings(
+        jax.eval_shape(init, key), mesh))(key)
     stats = schedule_stats(mcfg, spec, args.batch_size, args.seq_len,
                            data_shards=data_shards)
+    # where the weights landed: each device's bytes of the param tree
+    per_device: dict = {}
+    for leaf in jax.tree.leaves(params):
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = (per_device.get(shard.device.id, 0)
+                                           + shard.data.nbytes)
+    stats["param_bytes_per_device"] = [per_device[d] for d in sorted(per_device)]
 
     @jax.jit
     def step_fn(params, batch):

@@ -28,15 +28,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.common import cdiv, round_up, shard_map_unchecked
+from repro.common import cdiv, round_up
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init
 from repro.sharding.partition import MeshAxes
-
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def init_moe(key, cfg: ModelConfig) -> dict:
@@ -230,10 +225,10 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
         else:
             w_specs = (P(ma.model, None, None), P(ma.model, None, None),
                        P(ma.model, None, None))
-        y2d, aux = shard_map_unchecked(
-            body, mesh,
-            (batch_sharded, P(None, None)) + w_specs,
-            (batch_sharded, P()),
+        y2d, aux = jax.shard_map(
+            body, mesh=mesh,
+            in_specs=(batch_sharded, P(None, None)) + w_specs,
+            out_specs=(batch_sharded, P()), check_vma=False,
         )(x2d, params["router"]["w"], params["experts"]["w_gate"],
           params["experts"]["w_up"], params["experts"]["w_out"])
         return y2d.reshape(B, S, d), aux
@@ -249,11 +244,11 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig,
                               e_lo, E_local, cap)
         return jax.lax.psum(y, ma.model), aux
 
-    y2d, aux = shard_map_unchecked(
-        body_rep, mesh,
-        (P(None, None), P(None, None), P(ma.model, None, None),
-         P(ma.model, None, None), P(ma.model, None, None)),
-        (P(None, None), P()),
+    y2d, aux = jax.shard_map(
+        body_rep, mesh=mesh,
+        in_specs=(P(None, None), P(None, None), P(ma.model, None, None),
+                  P(ma.model, None, None), P(ma.model, None, None)),
+        out_specs=(P(None, None), P()), check_vma=False,
     )(x2d, params["router"]["w"], params["experts"]["w_gate"],
       params["experts"]["w_up"], params["experts"]["w_out"])
     return y2d.reshape(B, S, d), aux

@@ -209,7 +209,12 @@ class ActorSpec:
     faults (tamper/free-ride) with a per-uid seeded RNG;
     ``snapshot_dir`` turns on the crash-resume ``DiskSnapshotCache``;
     ``chaos`` (a ``runtime.chaos.FaultSchedule``) wraps the child's
-    transport; ``store_failover`` lists warm-standby store addresses."""
+    transport; ``store_failover`` lists warm-standby store addresses.
+
+    ``platform`` pins the child's JAX backend (``"cpu"``) before it
+    touches a device.  A TPU chip belongs to one process: a fleet spawned
+    by a parent that holds the chip must pin every child to the CPU, or
+    ``ActorSupervisor.spawn`` refuses it (``chip_owner_error``)."""
     kind: str                 # "miner" | "validator" | "server"
     uid: int
     stage: int                # -1 for validators
@@ -222,6 +227,7 @@ class ActorSpec:
     snapshot_dir: Optional[str] = None
     chaos: Any = None         # FaultSchedule | None
     store_failover: tuple = ()
+    platform: Optional[str] = None
 
 
 class ActorProcess:
@@ -883,7 +889,27 @@ _ACTOR_KINDS = {"miner": MinerActor, "validator": ValidatorActor,
 
 def _child_main(spec: ActorSpec, ready_queue: Any) -> None:
     """Spawn entry point (module-level: the child pickles a reference)."""
+    if spec.platform is not None:
+        jax.config.update("jax_platforms", spec.platform)
     _ACTOR_KINDS[spec.kind](spec).run(ready_queue)
+
+
+def chip_owner_error(specs: list) -> Optional[str]:
+    """Why ``specs`` cannot be spawned from this process, or None.
+
+    The parent of a fleet always runs JAX itself (anchors, sampling), so
+    on a TPU host it holds the chip, and a child that reaches for the
+    chip would fail on libtpu's lock or wait on it.  Children pinned to
+    the CPU through ``ActorSpec.platform`` are fine."""
+    if jax.default_backend() != "tpu":
+        return None
+    unpinned = [f"{s.kind}{s.uid}" for s in specs if s.platform != "cpu"]
+    if not unpinned:
+        return None
+    return (f"cannot spawn actors {unpinned} from a process that holds the "
+            f"TPU: a chip belongs to one process.  Run the in-process "
+            f"runtime on the chip, or pin every child to the CPU with "
+            f"ActorSpec(platform='cpu')")
 
 
 class ActorSupervisor:
@@ -903,6 +929,9 @@ class ActorSupervisor:
         import multiprocessing as mp
         import queue as queue_mod
 
+        err = chip_owner_error(specs)
+        if err is not None:
+            raise RuntimeError(err)
         ctx = mp.get_context("spawn")
         ready = ctx.Queue()
         for spec in specs:

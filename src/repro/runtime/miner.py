@@ -140,7 +140,10 @@ class Miner:
         self._pending = {}
 
     def snapshot(self) -> dict:
-        """State a validator copies at full sync to track this miner."""
-        return {"params": jax.tree.map(jnp.copy, self.params),
-                "opt_state": jax.tree.map(jnp.copy, self.opt_state),
-                "inner_step": self.inner_step}
+        """State a validator copies at full sync to track this miner, in
+        host memory: it stays cold until one validator replays it, and on
+        device the epoch-start copies of every miner sharing a chip would
+        double what the swarm keeps there."""
+        return jax.device_get({"params": self.params,
+                               "opt_state": self.opt_state,
+                               "inner_step": self.inner_step})

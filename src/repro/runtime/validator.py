@@ -86,9 +86,9 @@ class Validator:
 
         state).  ``labels_for`` maps sample_key -> labels (the validator
         reads the same dataset shard).  Scores are assigned per §3."""
-        params = snapshot["params"]
-        opt_state = snapshot["opt_state"]
-        inner_step = snapshot["inner_step"]
+        params, opt_state, inner_step = jax.device_put(
+            (snapshot["params"], snapshot["opt_state"],
+             snapshot["inner_step"]))
         opt = miner.opt
         spec, role = miner.spec, miner.role
 

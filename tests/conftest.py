@@ -14,23 +14,6 @@ import pytest
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, SRC)
 
-# prepended to every multi-device subprocess: jax<=0.4.x has no
-# jax.sharding.AxisType — fall back to the positional mesh (explicit axis
-# types are an optimisation hint here, not semantics)
-MESH_COMPAT = """
-import jax
-
-
-def make_mesh(shape, names):
-    try:
-        return jax.make_mesh(shape, names,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(names))
-    except AttributeError:
-        return jax.make_mesh(shape, names)
-"""
-
-
 def run_py(code: str, devices: int = 8) -> str:
     """Run ``code`` in a fresh interpreter with ``devices`` forced host
     devices (the count must be fixed before jax initialises)."""
@@ -38,7 +21,7 @@ def run_py(code: str, devices: int = 8) -> str:
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=SRC)
     proc = subprocess.run(
-        [sys.executable, "-c", MESH_COMPAT + textwrap.dedent(code)],
+        [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, env=env, timeout=900)
     assert proc.returncode == 0, proc.stderr[-4000:]
     return proc.stdout

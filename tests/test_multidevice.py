@@ -22,7 +22,8 @@ def test_pipeline_matches_sequential_when_uncompressed():
 
         cfg = dataclasses.replace(smoke_variant(get('llama3.2-1b')).model,
                                   n_layers=4)
-        mesh = make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         spec = PipelineSpec(n_stages=4, n_microbatches=2, compress=False)
         params = init_pipeline_params(jax.random.key(0), cfg, spec)
         x = jax.random.normal(jax.random.key(1), (2, 4, 16, cfg.d_model),
@@ -59,7 +60,8 @@ def test_butterfly_mesh_allreduce_and_diloco():
         from repro.core.butterfly import butterfly_all_reduce_mesh
         from repro.core import diloco
 
-        mesh = make_mesh((2, 4), ('pod', 'data'))
+        mesh = jax.make_mesh((2, 4), ('pod', 'data'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         x = jnp.arange(103, dtype=jnp.float32)        # odd length: padding
         with mesh:
             m, a = jax.jit(lambda x: butterfly_all_reduce_mesh(
@@ -94,7 +96,8 @@ def test_moe_ep_matches_local_path():
                               jnp.float32)
         y_local, aux_local = moe.moe_ffn(params, x, mcfg, None)
 
-        mesh = make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ma = make_mesh_axes(mesh, mcfg, cfg.parallel)
         with mesh:
             y_ep, aux_ep = jax.jit(lambda p, x: moe.moe_ffn(
@@ -123,7 +126,8 @@ def test_sharded_train_step_matches_single_device():
         batch = model.synth_batch(jax.random.key(1), 8, 32)
         _, m1 = jax.jit(lambda s, b: model.train_step(s, b))(state, batch)
 
-        mesh = make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         ma = make_mesh_axes(mesh, cfg.model, cfg.parallel)
         with mesh:
             _, m2 = jax.jit(lambda s, b: model.train_step(s, b, ma))(
