@@ -66,6 +66,7 @@ from repro.api.messages import (
     TickLossMsg,
     WeightUploadMsg,
 )
+from repro.common import span
 from repro.core import butterfly, clasp, compression, diloco
 
 
@@ -121,72 +122,80 @@ class TrainingPhase:
                 "engine (repro.core.pipeline / launch.train)")
         tp, schema = swarm.transport, swarm.transport.schema
         for tick in range(S.inner_steps):
-            batch = swarm.corpus.batch(swarm.global_tick)
-            swarm.global_tick += 1
-            # SWARM routing: sample one available miner per stage, reroute
-            pathway = []
-            ok = True
-            for s in range(S.n_stages):
-                avail = [m for m in swarm.stage_miners(s)
-                         if swarm.available(m, tick)]
-                if not avail:
-                    ok = False
-                    break
-                pathway.append(avail[swarm.rng.randint(len(avail))])
-            if not ok:
-                state.stalled += 1     # a whole layer offline: pipeline stall
-                continue
+            with span("tick"):
+                with span("batch"):
+                    batch = swarm.corpus.batch(swarm.global_tick)
+                swarm.global_tick += 1
+                # SWARM routing: sample one available miner per stage,
+                # reroute
+                pathway = []
+                ok = True
+                for s in range(S.n_stages):
+                    avail = [m for m in swarm.stage_miners(s)
+                             if swarm.available(m, tick)]
+                    if not avail:
+                        ok = False
+                        break
+                    pathway.append(avail[swarm.rng.randint(len(avail))])
+                if not ok:
+                    # a whole layer offline: pipeline stall
+                    state.stalled += 1
+                    continue
 
-            tok_msg = ActivationMsg.tokens(state.epoch, tick)
-            tp.publish(tok_msg, jnp.asarray(batch["tokens"]),
-                       actor="orchestrator")
-            # ---------------- forward chain ----------------
-            in_key = tok_msg.key(schema)
-            last_in_key = in_key
-            for s, miner in enumerate(pathway):
-                out_msg = ActivationMsg(state.epoch, tick, s, miner.uid)
-                out_key = out_msg.key(schema)
-                if s == S.n_stages - 1:
-                    last_in_key = in_key
-                out = miner.forward(tick, in_key, out_key)
-                # an adversarial miner uploads a corrupted activation in
-                # place of its honest output — validators catch the mismatch
-                # on replay, CLASP catches the downstream loss inflation
-                b = swarm.faults.behavior(miner.uid)
-                if s < S.n_stages - 1 and (b.free_ride
-                                           or b.tamper_activations > 0):
-                    corrupted = swarm.faults.corrupt_activation(
-                        miner.uid, np.asarray(out, np.float32))
-                    tp.publish(out_msg,
-                               jnp.asarray(corrupted).astype(out.dtype),
-                               actor=miner.actor)
-                in_key = out_key
-            last = pathway[-1]
-            labels = jnp.asarray(batch["labels"])
-            state.labels_for[last_in_key] = labels
+                tok_msg = ActivationMsg.tokens(state.epoch, tick)
+                with span("batch"):
+                    tp.publish(tok_msg, jnp.asarray(batch["tokens"]),
+                               actor="orchestrator")
+                # ---------------- forward chain ----------------
+                in_key = tok_msg.key(schema)
+                last_in_key = in_key
+                for s, miner in enumerate(pathway):
+                    out_msg = ActivationMsg(state.epoch, tick, s, miner.uid)
+                    out_key = out_msg.key(schema)
+                    if s == S.n_stages - 1:
+                        last_in_key = in_key
+                    out = miner.forward(tick, in_key, out_key)
+                    # an adversarial miner uploads a corrupted activation
+                    # in place of its honest output — validators catch the
+                    # mismatch on replay, CLASP catches the downstream loss
+                    # inflation
+                    b = swarm.faults.behavior(miner.uid)
+                    if s < S.n_stages - 1 and (b.free_ride
+                                               or b.tamper_activations > 0):
+                        corrupted = swarm.faults.corrupt_activation(
+                            miner.uid, np.asarray(out, np.float32))
+                        tp.publish(out_msg,
+                                   jnp.asarray(corrupted).astype(out.dtype),
+                                   actor=miner.actor)
+                    in_key = out_key
+                last = pathway[-1]
+                labels = jnp.asarray(batch["labels"])
+                state.labels_for[last_in_key] = labels
 
-            # ---------------- backward chain ----------------
-            loss, g = last.backward_last(last_in_key, labels)
-            state.records.append(clasp.PathwayRecord(
-                tuple(m.uid for m in pathway), loss))
-            for s in range(S.n_stages - 2, -1, -1):
-                miner = pathway[s]
-                msg = GradientMsg(state.epoch, tick, s, miner.uid)
-                if S.wire_codec == "int8":
-                    # the paper's symmetric compression: gradient hand-offs
-                    # ship as blockwise-int8 codes (store bytes and the
-                    # simulated clock see the real on-wire size); miners
-                    # train on the dequantized codes, and validator replay
-                    # decodes the same payload, so both sides see one wire
-                    flat = jnp.ravel(jnp.asarray(g, jnp.float32))
-                    payload = dict(compression.encode(flat, "int8"),
-                                   shape=tuple(np.shape(g)))
-                    tp.publish(msg, payload, actor="orchestrator")
-                    g = jnp.reshape(compression.decode(payload),
-                                    np.shape(g)).astype(jnp.asarray(g).dtype)
-                else:
-                    tp.publish(msg, g, actor="orchestrator")
-                g = miner.backward(miner.work_log[-1].sample_key, g)
+                # ---------------- backward chain ----------------
+                loss, g = last.backward_last(last_in_key, labels)
+                state.records.append(clasp.PathwayRecord(
+                    tuple(m.uid for m in pathway), loss))
+                for s in range(S.n_stages - 2, -1, -1):
+                    miner = pathway[s]
+                    msg = GradientMsg(state.epoch, tick, s, miner.uid)
+                    if S.wire_codec == "int8":
+                        # the paper's symmetric compression: gradient
+                        # hand-offs ship as blockwise-int8 codes (store
+                        # bytes and the simulated clock see the real
+                        # on-wire size); miners train on the dequantized
+                        # codes, and validator replay decodes the same
+                        # payload, so both sides see one wire
+                        flat = jnp.ravel(jnp.asarray(g, jnp.float32))
+                        payload = dict(compression.encode(flat, "int8"),
+                                       shape=tuple(np.shape(g)))
+                        tp.publish(msg, payload, actor="orchestrator")
+                        g = jnp.reshape(
+                            compression.decode(payload),
+                            np.shape(g)).astype(jnp.asarray(g).dtype)
+                    else:
+                        tp.publish(msg, g, actor="orchestrator")
+                    g = miner.backward(miner.work_log[-1].sample_key, g)
 
 
 class ValidationPhase:
@@ -258,14 +267,15 @@ class SharingPhase:
             for idx, m in enumerate(qual):
                 vec = m.weights_vector()
                 vec = swarm.faults.corrupt_weights(m.uid, vec)
-                payload = compression.encode(jnp.asarray(vec),
-                                             S.share_codec)
-                swarm.transport.publish(
-                    WeightUploadMsg(state.epoch, s, m.uid,
-                                    codec=S.share_codec),
-                    payload, actor=m.actor)
-                uploads[idx] = np.asarray(
-                    compression.decode(payload, vec.shape[0]))
+                with span("share.upload"):
+                    payload = compression.encode(jnp.asarray(vec),
+                                                 S.share_codec)
+                    swarm.transport.publish(
+                        WeightUploadMsg(state.epoch, s, m.uid,
+                                        codec=S.share_codec),
+                        payload, actor=m.actor)
+                    uploads[idx] = np.asarray(
+                        compression.decode(payload, vec.shape[0]))
         state.qualified[s] = qual
         state.uploads[s] = uploads
 
@@ -328,10 +338,11 @@ class SyncPhase:
         tamper = {idx: swarm.faults.behavior(m.uid).tamper_weights
                   for idx, m in enumerate(qual)
                   if swarm.faults.behavior(m.uid).tamper_weights > 0}
-        copies = butterfly.reduce_with_copies(plan, uploads,
-                                              tamper=tamper or None)
-        state.agreement[s] = butterfly.agreement_matrix(plan, copies)
-        merged, _, _ = butterfly.reduce_shards(plan, uploads)
+        with span("sync.reduce"):
+            copies = butterfly.reduce_with_copies(plan, uploads,
+                                                  tamper=tamper or None)
+            state.agreement[s] = butterfly.agreement_matrix(plan, copies)
+            merged, _, _ = butterfly.reduce_shards(plan, uploads)
         return merged
 
     def _reduce_sharded(self, swarm, state: EpochState, s: int,
@@ -351,23 +362,25 @@ class SyncPhase:
                                   merged: np.ndarray) -> None:
         S = swarm.config
         # --- DiLoCo outer step on the per-stage anchor ---
-        _, unravel = ravel_pytree(
-            jax.tree.map(lambda x: x.astype(jnp.float32),
-                         swarm.anchors[s]))
-        avg = unravel(jnp.asarray(merged))
-        swarm.outer[s] = diloco.outer_update(
-            swarm.outer[s], avg, outer_lr=S.outer_lr,
-            outer_momentum=S.outer_momentum)
-        swarm.anchors[s] = jax.tree.map(
-            lambda a, p: a.astype(p.dtype), swarm.outer[s].anchor,
-            swarm.anchors[s])
+        with span("sync.outer_step"):
+            _, unravel = ravel_pytree(
+                jax.tree.map(lambda x: x.astype(jnp.float32),
+                             swarm.anchors[s]))
+            avg = unravel(jnp.asarray(merged))
+            swarm.outer[s] = diloco.outer_update(
+                swarm.outer[s], avg, outer_lr=S.outer_lr,
+                outer_momentum=S.outer_momentum)
+            swarm.anchors[s] = jax.tree.map(
+                lambda a, p: a.astype(p.dtype), swarm.outer[s].anchor,
+                swarm.anchors[s])
         # --- full sync: every miner (incl. stragglers/joiners) downloads
-        anchor_vec, _ = ravel_pytree(
-            jax.tree.map(lambda x: x.astype(jnp.float32),
-                         swarm.anchors[s]))
         msg = AnchorMsg(state.epoch, s)
-        swarm.transport.publish(msg, np.asarray(anchor_vec),
-                                actor="orchestrator")
+        with span("sync.anchor_publish"):
+            anchor_vec, _ = ravel_pytree(
+                jax.tree.map(lambda x: x.astype(jnp.float32),
+                             swarm.anchors[s]))
+            swarm.transport.publish(msg, np.asarray(anchor_vec),
+                                    actor="orchestrator")
         with swarm.transport.parallel():
             for m in swarm.stage_miners(s):
                 vec = swarm.transport.fetch(msg, actor=m.actor)
@@ -518,71 +531,77 @@ class EpochDriver:
         return min(self._pins.values()) if self._pins else None
 
     def run_epoch(self, swarm) -> EpochStats:
-        for m in swarm.miners.values():
-            m.reset_epoch()
-        state = EpochState(
-            epoch=swarm.epoch,
-            snapshots={uid: m.snapshot()
-                       for uid, m in swarm.miners.items()})
-        for phase in self.phases:
-            phase.run(swarm, state)
-        return self._finalize(swarm, state)
+        with span("epoch"):
+            for m in swarm.miners.values():
+                m.reset_epoch()
+            state = EpochState(
+                epoch=swarm.epoch,
+                snapshots={uid: m.snapshot()
+                           for uid, m in swarm.miners.items()})
+            for phase in self.phases:
+                with span("phase." + phase.name):
+                    phase.run(swarm, state)
+            return self._finalize(swarm, state)
 
     def _finalize(self, swarm, state: EpochState) -> EpochStats:
         """Fold the epoch scratchpad into ``EpochStats`` and GC the store —
         shared by the lockstep and event-driven timelines."""
-        if not state.batches:
-            # a timeline without SharingPhase still reports the batch census
-            state.batches = {m.uid: m.batches_done
-                             for m in swarm.miners.values()}
-            state.b_eff = diloco.effective_batch(state.batches,
-                                                 swarm.config.b_min)
+        with span("finalize"):
+            if not state.batches:
+                # a timeline without SharingPhase still reports the batch
+                # census
+                state.batches = {m.uid: m.batches_done
+                                 for m in swarm.miners.values()}
+                state.b_eff = diloco.effective_batch(state.batches,
+                                                     swarm.config.b_min)
 
-        n_miners = len(swarm.miners)
-        layer_of = np.array([swarm.miners[u].stage
-                             for u in sorted(swarm.miners.keys())])
-        report = (clasp.attribute(state.records, n_miners, layer_of)
-                  if state.records else None)
-        t_now = swarm.epoch * swarm.config.sync_interval_hours
-        swarm.ledger.prune(t_now)
-        emissions = swarm.ledger.emissions(
-            t_now, miners=sorted(swarm.miners.keys()))
+            n_miners = len(swarm.miners)
+            layer_of = np.array([swarm.miners[u].stage
+                                 for u in sorted(swarm.miners.keys())])
+            report = (clasp.attribute(state.records, n_miners, layer_of)
+                      if state.records else None)
+            t_now = swarm.epoch * swarm.config.sync_interval_hours
+            swarm.ledger.prune(t_now)
+            emissions = swarm.ledger.emissions(
+                t_now, miners=sorted(swarm.miners.keys()))
 
-        stats = EpochStats(
-            epoch=swarm.epoch,
-            mean_loss=float(np.mean([r.loss for r in state.records]))
-            if state.records else float("nan"),
-            b_eff=state.b_eff,
-            batches=dict(state.batches),
-            merged_stages=state.merged_stages,
-            stalled_ticks=state.stalled,
-            agreement=state.agreement,
-            clasp=report,
-            validation=state.validation,
-            emissions=emissions,
-            reduce_audits=state.reduce_audits,
-            replanned_ticks=state.replanned,
-        )
-        swarm.history.append(stats)
-        swarm.epoch += 1
-        # activations from this epoch are garbage-collected from the store
-        schema = swarm.transport.schema
-        swarm.transport.delete_prefix(
-            schema.activations_prefix(stats.epoch))
-        # weight/score planes: retention-window GC.  The seed behaviour
-        # (keep everything, for replay/audit) is retain_epochs=None; with a
-        # window of K, only the last K epochs' weights/ and scores/ survive
-        # — long runs no longer grow the store without bound
-        retain = swarm.config.retain_epochs
-        if retain is not None:
-            pin = self._pin_floor()
-            while self._gc_floor <= stats.epoch - retain \
-                    and (pin is None or self._gc_floor < pin):
-                e = self._gc_floor
-                swarm.transport.delete_prefix(schema.weights_prefix(e))
-                swarm.transport.delete_prefix(schema.scores_prefix(e))
-                self._gc_floor += 1
-        return stats
+            stats = EpochStats(
+                epoch=swarm.epoch,
+                mean_loss=float(np.mean([r.loss for r in state.records]))
+                if state.records else float("nan"),
+                b_eff=state.b_eff,
+                batches=dict(state.batches),
+                merged_stages=state.merged_stages,
+                stalled_ticks=state.stalled,
+                agreement=state.agreement,
+                clasp=report,
+                validation=state.validation,
+                emissions=emissions,
+                reduce_audits=state.reduce_audits,
+                replanned_ticks=state.replanned,
+            )
+            swarm.history.append(stats)
+            swarm.epoch += 1
+            # activations from this epoch are garbage-collected from the
+            # store
+            schema = swarm.transport.schema
+            swarm.transport.delete_prefix(
+                schema.activations_prefix(stats.epoch))
+            # weight/score planes: retention-window GC.  The seed
+            # behaviour (keep everything, for replay/audit) is
+            # retain_epochs=None; with a window of K, only the last K
+            # epochs' weights/ and scores/ survive — long runs no longer
+            # grow the store without bound
+            retain = swarm.config.retain_epochs
+            if retain is not None:
+                pin = self._pin_floor()
+                while self._gc_floor <= stats.epoch - retain \
+                        and (pin is None or self._gc_floor < pin):
+                    e = self._gc_floor
+                    swarm.transport.delete_prefix(schema.weights_prefix(e))
+                    swarm.transport.delete_prefix(schema.scores_prefix(e))
+                    self._gc_floor += 1
+            return stats
 
 
 class EventDriver(EpochDriver):

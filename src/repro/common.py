@@ -17,6 +17,23 @@ import numpy as np
 PyTree = Any
 
 # ---------------------------------------------------------------------------
+# Host spans
+# ---------------------------------------------------------------------------
+
+SPAN_PREFIX = "iota."
+
+
+def span(name: str, **counts) -> jax.profiler.TraceAnnotation:
+    """Host span ``iota.<name>`` in the JAX profiler's trace.
+
+    It records only while a trace is being taken, on the clock of the
+    device's op events, so an idle stretch of the chip can be put down to
+    the host call around it.  Spans wrap host calls only, never traced
+    code, and never wait on the device.  ``counts`` (``bytes=``) are the
+    span's metadata: pass only values already in hand when it opens."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **counts)
+
+# ---------------------------------------------------------------------------
 # Pytree helpers
 # ---------------------------------------------------------------------------
 

@@ -41,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common import cdiv
+from repro.common import cdiv, span
 from repro.core import compression
 from repro.kernels import ops
 
@@ -303,20 +303,21 @@ class ButterflyExecutor:
         """Publish miner ``idx``'s flat weight vector as per-shard payloads
         (empty shards are skipped); returns the minted keys."""
         from repro.api.messages import ShardUploadMsg
-        vec = jnp.asarray(vector, jnp.float32)
-        assert vec.shape[0] == self.plan.vector_len, \
-            (vec.shape, self.plan.vector_len)
-        keys = []
-        for s in range(self.plan.n_shards):
-            lo, hi = self.plan.shard_bounds(s)
-            if hi == lo:
-                continue
-            msg = ShardUploadMsg(self.epoch, self.stage, self.uids[idx], s,
-                                 codec=self.codec)
-            payload = compression.encode(vec[lo:hi], self.codec)
-            self.transport.publish(msg, payload, actor=actor)
-            keys.append(msg.key(self.transport.schema))
-        return keys
+        with span("share.upload"):
+            vec = jnp.asarray(vector, jnp.float32)
+            assert vec.shape[0] == self.plan.vector_len, \
+                (vec.shape, self.plan.vector_len)
+            keys = []
+            for s in range(self.plan.n_shards):
+                lo, hi = self.plan.shard_bounds(s)
+                if hi == lo:
+                    continue
+                msg = ShardUploadMsg(self.epoch, self.stage, self.uids[idx],
+                                     s, codec=self.codec)
+                payload = compression.encode(vec[lo:hi], self.codec)
+                self.transport.publish(msg, payload, actor=actor)
+                keys.append(msg.key(self.transport.schema))
+            return keys
 
     # -- step 2: reduce (actor = the assigned reducer) -------------------
 
@@ -342,25 +343,26 @@ class ButterflyExecutor:
         deceptive reducer adds a constant offset after the merge (same
         semantics as ``reduce_with_copies``)."""
         from repro.api.messages import ShardReducedMsg
-        n = self.plan.n_miners
-        width = assignment.hi - assignment.lo
-        blocks = np.zeros((n, width), np.float32)
-        valid = np.zeros((n,), bool)
-        for i, key in enumerate(assignment.upload_keys):
-            if not self.transport.exists(key):
-                continue                     # miner never uploaded: mask out
-            payload = self.transport.get(key, actor=actor)
-            blocks[i] = np.asarray(compression.decode(payload, width))
-            valid[i] = True
-        mean = np.asarray(ops.shard_merge(jnp.asarray(blocks),
-                                          jnp.asarray(valid)))
-        if tamper:
-            mean = mean + np.float32(tamper)
-        msg = ShardReducedMsg(self.epoch, self.stage, assignment.shard,
-                              assignment.reducer_uid)
-        self.transport.publish(msg, compression.encode(mean, "none"),
-                               actor=actor)
-        return mean
+        with span("sync.reduce"):
+            n = self.plan.n_miners
+            width = assignment.hi - assignment.lo
+            blocks = np.zeros((n, width), np.float32)
+            valid = np.zeros((n,), bool)
+            for i, key in enumerate(assignment.upload_keys):
+                if not self.transport.exists(key):
+                    continue             # miner never uploaded: mask out
+                payload = self.transport.get(key, actor=actor)
+                blocks[i] = np.asarray(compression.decode(payload, width))
+                valid[i] = True
+            mean = np.asarray(ops.shard_merge(jnp.asarray(blocks),
+                                              jnp.asarray(valid)))
+            if tamper:
+                mean = mean + np.float32(tamper)
+            msg = ShardReducedMsg(self.epoch, self.stage, assignment.shard,
+                                  assignment.reducer_uid)
+            self.transport.publish(msg, compression.encode(mean, "none"),
+                                   actor=actor)
+            return mean
 
     def run_reducer(self, idx: int, actor: str,
                     tamper: float = 0.0) -> list[ShardAssignment]:
@@ -391,40 +393,42 @@ class ButterflyExecutor:
         cannot poison the anchor as long as its partner is honest.  Only a
         shard whose *both* assignees are dishonest, or whose only
         surviving copy is tampered, degrades."""
-        copies: dict[tuple[int, int], np.ndarray] = {}
-        for s, (i, j) in enumerate(self.plan.pairs):
-            lo, hi = self.plan.shard_bounds(s)
-            if hi == lo:
-                continue
-            for r in (i, j):
-                key = self.reduced_key(s, r)
-                if not self.transport.exists(key):
+        with span("sync.collect"):
+            copies: dict[tuple[int, int], np.ndarray] = {}
+            for s, (i, j) in enumerate(self.plan.pairs):
+                lo, hi = self.plan.shard_bounds(s)
+                if hi == lo:
                     continue
-                payload = self.transport.get(key, actor=actor)
-                copies[(s, r)] = np.asarray(
-                    compression.decode(payload, hi - lo))
-        # per-reducer consensus: mean agreement over pairs with both copies
-        agree = agreement_matrix(self.plan, copies)
-        self.last_agreement = agree
-        n = self.plan.n_miners
-        consensus = np.array([
-            np.nanmean(agree[m][np.arange(n) != m])
-            if np.any(~np.isnan(agree[m][np.arange(n) != m])) else 1.0
-            for m in range(n)])
-        merged = np.zeros(self.plan.vector_len, np.float32)
-        shard_valid = np.zeros(self.plan.n_shards, bool)
-        for s, (i, j) in enumerate(self.plan.pairs):
-            lo, hi = self.plan.shard_bounds(s)
-            if hi == lo:
+                for r in (i, j):
+                    key = self.reduced_key(s, r)
+                    if not self.transport.exists(key):
+                        continue
+                    payload = self.transport.get(key, actor=actor)
+                    copies[(s, r)] = np.asarray(
+                        compression.decode(payload, hi - lo))
+            # per-reducer consensus: mean agreement over pairs with both
+            # copies
+            agree = agreement_matrix(self.plan, copies)
+            self.last_agreement = agree
+            n = self.plan.n_miners
+            consensus = np.array([
+                np.nanmean(agree[m][np.arange(n) != m])
+                if np.any(~np.isnan(agree[m][np.arange(n) != m])) else 1.0
+                for m in range(n)])
+            merged = np.zeros(self.plan.vector_len, np.float32)
+            shard_valid = np.zeros(self.plan.n_shards, bool)
+            for s, (i, j) in enumerate(self.plan.pairs):
+                lo, hi = self.plan.shard_bounds(s)
+                if hi == lo:
+                    shard_valid[s] = True
+                    continue
+                present = [r for r in (i, j) if (s, r) in copies]
+                if not present:
+                    continue             # both assignees down: lost
+                best = max(present, key=lambda r: (consensus[r], -r))
+                merged[lo:hi] = copies[(s, best)]
                 shard_valid[s] = True
-                continue
-            present = [r for r in (i, j) if (s, r) in copies]
-            if not present:
-                continue                     # both assignees down: lost
-            best = max(present, key=lambda r: (consensus[r], -r))
-            merged[lo:hi] = copies[(s, best)]
-            shard_valid[s] = True
-        return merged, shard_valid, copies
+            return merged, shard_valid, copies
 
 
 def store_agreement(transport, epoch: int, stage: int,
@@ -461,9 +465,12 @@ def store_agreement(transport, epoch: int, stage: int,
         if len(entries) != 2:
             continue                         # copy lost: nothing to compare
         (ua, ka), (ub, kb) = sorted(entries)
-        a = np.asarray(compression.decode(transport.get(ka, actor=actor)))
-        b = np.asarray(compression.decode(transport.get(kb, actor=actor)))
-        ok = float(np.allclose(a, b, rtol=1e-4, atol=1e-5))
+        with span("audit.compare"):
+            a = np.asarray(compression.decode(transport.get(ka,
+                                                            actor=actor)))
+            b = np.asarray(compression.decode(transport.get(kb,
+                                                            actor=actor)))
+            ok = float(np.allclose(a, b, rtol=1e-4, atol=1e-5))
         agree[pos[ua], pos[ub]] = agree[pos[ub], pos[ua]] = ok
     if len(uids):
         np.fill_diagonal(agree, 1.0)

@@ -103,6 +103,7 @@ def _flash_call(q, k, v, *, causal, q_offset, scale, interpret,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q, k, v)
 
 

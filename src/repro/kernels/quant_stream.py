@@ -78,6 +78,7 @@ def quantize_int8(x, block: int = BLOCK, interpret: bool = False):
                    jax.ShapeDtypeStruct((n_blocks,), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((k * rp, width), jnp.float32)],
         interpret=interpret,
+        name="quantize_int8",
     )(x.reshape(n // width, width))
     return q.reshape(n), s
 
@@ -101,6 +102,7 @@ def dequantize_int8(q, scales, block: int = BLOCK, interpret: bool = False):
         out_shape=jax.ShapeDtypeStruct((n // width, width), jnp.float32),
         scratch_shapes=[pltpu.VMEM((k * rp, width), jnp.float32)],
         interpret=interpret,
+        name="dequantize_int8",
     )(q.reshape(n // width, width), scales)
     return out.reshape(n)
 

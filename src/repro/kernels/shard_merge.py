@@ -42,4 +42,5 @@ def shard_merge(shards, valid, interpret: bool = False):
         out_specs=pl.BlockSpec((cols,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((L,), jnp.float32),
         interpret=interpret,
+        name="shard_merge",
     )(shards, valid.astype(jnp.float32).reshape(M, 1))

@@ -14,7 +14,7 @@ from jax.flatten_util import ravel_pytree
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common import tree_cast
+from repro.common import span, tree_cast
 from repro.configs.base import TrainConfig
 from repro.optim import adamw
 from repro.optim.schedules import cosine_warmup
@@ -74,33 +74,37 @@ class Miner:
 
     def forward(self, tick: int, sample_key: str, out_key: str) -> Any:
         """Read input from the store, apply the stage, upload the output."""
-        x_in = self.transport.get(sample_key, actor=self.actor)
-        out = sm.stage_forward(self.params, x_in, self.spec, self.role)
-        self._pending[sample_key] = x_in
-        self.transport.put(out_key, out, actor=self.actor)
-        self.work_log.append(WorkItem(tick, sample_key, out_key))
-        return out
+        with span("miner.forward"):
+            x_in = self.transport.get(sample_key, actor=self.actor)
+            out = sm.stage_forward(self.params, x_in, self.spec, self.role)
+            self._pending[sample_key] = x_in
+            self.transport.put(out_key, out, actor=self.actor)
+            self.work_log.append(WorkItem(tick, sample_key, out_key))
+            return out
 
     def backward_last(self, sample_key: str, labels) -> tuple[float, Any]:
         """Last-stage miner: compute loss + grads, return (loss, g_z_in)."""
-        z_in = self._pending.pop(sample_key)
-        loss, g_params, g_z = sm.last_stage_loss_and_grads(
-            self.params, z_in, labels, self.spec)
-        self._apply(g_params)
-        return float(loss), g_z
+        with span("miner.backward_last"):
+            z_in = self._pending.pop(sample_key)
+            loss, g_params, g_z = sm.last_stage_loss_and_grads(
+                self.params, z_in, labels, self.spec)
+            self._apply(g_params)
+            return float(loss), g_z
 
     def backward(self, sample_key: str, g_out) -> Any:
         """Mid/first miner: VJP through the recomputed stage forward."""
-        x_in = self._pending.pop(sample_key)
-        g_params, g_x = sm.stage_backward(self.params, x_in, g_out,
-                                          self.spec, self.role)
-        self._apply(g_params)
-        return g_x
+        with span("miner.backward"):
+            x_in = self._pending.pop(sample_key)
+            g_params, g_x = sm.stage_backward(self.params, x_in, g_out,
+                                              self.spec, self.role)
+            self._apply(g_params)
+            return g_x
 
     def _apply(self, grads) -> None:
-        self.params, self.opt_state = self.opt.update(
-            grads, self.opt_state, self.params, self.inner_step)
-        self.inner_step = self.inner_step + 1
+        with span("optimizer"):
+            self.params, self.opt_state = self.opt.update(
+                grads, self.opt_state, self.params, self.inner_step)
+            self.inner_step = self.inner_step + 1
         self.batches_done += 1
         if self.work_log:
             self.work_log[-1].did_backward = True
@@ -110,9 +114,10 @@ class Miner:
     # ------------------------------------------------------------------
 
     def weights_vector(self) -> np.ndarray:
-        flat, _ = ravel_pytree(
-            jax.tree.map(lambda x: x.astype(jnp.float32), self.params))
-        return np.asarray(flat)
+        with span("share.vector"):
+            flat, _ = ravel_pytree(
+                jax.tree.map(lambda x: x.astype(jnp.float32), self.params))
+            return np.asarray(flat)
 
     def run_reduce(self, executor, idx: int, tamper: float = 0.0) -> int:
         """Perform this miner's assigned butterfly reduce work through the
@@ -127,11 +132,12 @@ class Miner:
         return len(done)
 
     def load_weights_vector(self, vec: np.ndarray) -> None:
-        flat, unravel = ravel_pytree(
-            jax.tree.map(lambda x: x.astype(jnp.float32), self.params))
-        new = unravel(jnp.asarray(vec, jnp.float32))
-        self.params = jax.tree.map(lambda n, p: n.astype(p.dtype),
-                                   new, self.params)
+        with span("sync.anchor_load"):
+            flat, unravel = ravel_pytree(
+                jax.tree.map(lambda x: x.astype(jnp.float32), self.params))
+            new = unravel(jnp.asarray(vec, jnp.float32))
+            self.params = jax.tree.map(lambda n, p: n.astype(p.dtype),
+                                       new, self.params)
 
     def reset_epoch(self) -> None:
         self.batches_done = 0
@@ -144,6 +150,7 @@ class Miner:
         host memory: it stays cold until one validator replays it, and on
         device the epoch-start copies of every miner sharing a chip would
         double what the swarm keeps there."""
-        return jax.device_get({"params": self.params,
-                               "opt_state": self.opt_state,
-                               "inner_step": self.inner_step})
+        with span("snapshot"):
+            return jax.device_get({"params": self.params,
+                                   "opt_state": self.opt_state,
+                                   "inner_step": self.inner_step})

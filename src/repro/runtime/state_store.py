@@ -18,6 +18,7 @@ import jax
 from jax.flatten_util import ravel_pytree
 import numpy as np
 
+from repro.common import span
 from repro.core import compression
 
 
@@ -101,17 +102,23 @@ class StateStore:
         """Store ``value``; returns the full ``StoreEntry`` so callers that
         need the byte count (the simulated-network hot loop) don't pay a
         second lookup.  The entry carries the digest for tamper evidence."""
-        if codec and codec != "none":
-            flat, _ = ravel_pytree(value)
-            value = compression.encode(flat, codec)
-        nbytes = _nbytes(value)
-        digest = _digest(value)
-        entry = StoreEntry(value, nbytes, digest,
-                           dict(meta or {}, codec=codec or "none"))
-        self._data[key] = entry
-        self.uploaded[self._ns(key)] += nbytes
-        self.uploads_by_actor[actor] += nbytes
-        return entry
+        with span("store.put"):
+            if codec and codec != "none":
+                with span("store.encode"):
+                    flat, _ = ravel_pytree(value)
+                    value = compression.encode(flat, codec)
+            # the first np.asarray of a device value is its copy to the
+            # host, and waits for the program that produces it
+            with span("store.copy"):
+                nbytes = _nbytes(value)
+            with span("store.hash"):
+                digest = _digest(value)
+            entry = StoreEntry(value, nbytes, digest,
+                               dict(meta or {}, codec=codec or "none"))
+            self._data[key] = entry
+            self.uploaded[self._ns(key)] += nbytes
+            self.uploads_by_actor[actor] += nbytes
+            return entry
 
     def _nearest_prefix(self, key: str) -> tuple[str, int]:
         """Longest '/'-segment prefix of ``key`` under which keys exist."""
@@ -136,9 +143,10 @@ class StateStore:
         entry = self._data.get(key)
         if entry is None:
             raise self._missing(key, actor)
-        self.downloaded[self._ns(key)] += entry.nbytes
-        self.downloads_by_actor[actor] += entry.nbytes
-        return entry
+        with span("store.get", bytes=entry.nbytes):
+            self.downloaded[self._ns(key)] += entry.nbytes
+            self.downloads_by_actor[actor] += entry.nbytes
+            return entry
 
     def get_entry(self, key: str) -> StoreEntry:
         entry = self._data.get(key)

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common import cosine_similarity
+from repro.common import cosine_similarity, span
 from repro.core import butterfly, compression
 from repro.core.incentives import IncentiveLedger
 from repro.kernels import ops
@@ -86,56 +86,63 @@ class Validator:
 
         state).  ``labels_for`` maps sample_key -> labels (the validator
         reads the same dataset shard).  Scores are assigned per §3."""
-        params, opt_state, inner_step = jax.device_put(
-            (snapshot["params"], snapshot["opt_state"],
-             snapshot["inner_step"]))
-        opt = miner.opt
-        spec, role = miner.spec, miner.role
+        with span("validate"):
+            with span("validate.restore"):
+                params, opt_state, inner_step = jax.device_put(
+                    (snapshot["params"], snapshot["opt_state"],
+                     snapshot["inner_step"]))
+            opt = miner.opt
+            spec, role = miner.spec, miner.role
 
-        checked = passed = 0
-        validated_backwards = 0.0
-        min_cos = 1.0
-        items = miner.work_log if max_items is None else miner.work_log[:max_items]
-        for item in items:
-            x_in = self.transport.get(item.sample_key, actor=self.actor)
-            mine = sm.stage_forward(params, x_in, spec, role)
-            theirs = self.transport.get(item.out_key, actor=self.actor)
-            cos = float(cosine_similarity(jnp.asarray(mine, jnp.float32),
-                                          jnp.asarray(theirs, jnp.float32)))
-            checked += 1
-            min_cos = min(min_cos, cos)
-            ok = cos >= COSINE_THRESHOLD
-            passed += int(ok)
-            if not item.did_backward:
-                continue
-            # replay the miner's local update so later items line up
-            if role == "last":
-                labels = labels_for[item.sample_key]
-                _, g_params, _ = sm.last_stage_loss_and_grads(
-                    params, x_in, labels, spec)
-            else:
-                g_out_key = self.transport.schema.gradient_for(item.out_key)
-                if not self.transport.exists(g_out_key):
+            checked = passed = 0
+            validated_backwards = 0.0
+            min_cos = 1.0
+            items = miner.work_log if max_items is None \
+                else miner.work_log[:max_items]
+            for item in items:
+                x_in = self.transport.get(item.sample_key, actor=self.actor)
+                mine = sm.stage_forward(params, x_in, spec, role)
+                theirs = self.transport.get(item.out_key, actor=self.actor)
+                cos = float(cosine_similarity(
+                    jnp.asarray(mine, jnp.float32),
+                    jnp.asarray(theirs, jnp.float32)))
+                checked += 1
+                min_cos = min(min_cos, cos)
+                ok = cos >= COSINE_THRESHOLD
+                passed += int(ok)
+                if not item.did_backward:
                     continue
-                g_out = self.transport.get(g_out_key, actor=self.actor)
-                if isinstance(g_out, dict) and g_out.get("codec"):
-                    # int8 gradient wire (SwarmConfig.wire_codec): replay
-                    # with the same dequantized codes the miner trained on
-                    from repro.core import compression
-                    g_out = jnp.reshape(compression.decode(g_out),
-                                        g_out["shape"])
-                g_params, _ = sm.stage_backward(params, x_in, g_out, spec, role)
-            params, opt_state = opt.update(g_params, opt_state, params,
-                                           inner_step)
-            inner_step = inner_step + 1
-            if ok:
-                validated_backwards += 1.0
+                # replay the miner's local update so later items line up
+                if role == "last":
+                    labels = labels_for[item.sample_key]
+                    _, g_params, _ = sm.last_stage_loss_and_grads(
+                        params, x_in, labels, spec)
+                else:
+                    g_out_key = self.transport.schema.gradient_for(
+                        item.out_key)
+                    if not self.transport.exists(g_out_key):
+                        continue
+                    g_out = self.transport.get(g_out_key, actor=self.actor)
+                    if isinstance(g_out, dict) and g_out.get("codec"):
+                        # int8 gradient wire (SwarmConfig.wire_codec):
+                        # replay with the same dequantized codes the miner
+                        # trained on
+                        from repro.core import compression
+                        g_out = jnp.reshape(compression.decode(g_out),
+                                            g_out["shape"])
+                    g_params, _ = sm.stage_backward(params, x_in, g_out,
+                                                    spec, role)
+                params, opt_state = opt.update(g_params, opt_state, params,
+                                               inner_step)
+                inner_step = inner_step + 1
+                if ok:
+                    validated_backwards += 1.0
 
-        result = ValidationResult(miner.uid, epoch, checked, passed,
-                                  validated_backwards, min_cos)
-        self.results.append(result)
-        self.ledger.record(miner.uid, epoch, result.score, t_now)
-        return result
+            result = ValidationResult(miner.uid, epoch, checked, passed,
+                                      validated_backwards, min_cos)
+            self.results.append(result)
+            self.ledger.record(miner.uid, epoch, result.score, t_now)
+            return result
 
     # ------------------------------------------------------------------
     # sharded-sync reduce audits (§5.2 agreement, from wire artifacts)
@@ -146,14 +153,15 @@ class Validator:
         every shard has two independent reduced copies, so a deceptive
         reducer disagrees with *all* of its partners (Fig 7a) — visible to
         anyone who can read the store, which is the §5 trustless claim."""
-        uids, agree = butterfly.store_agreement(self.transport, epoch,
-                                                stage, actor=self.actor)
-        flagged = []
-        for i, uid in enumerate(uids):
-            others = agree[i][np.arange(len(uids)) != i]
-            if others.size and np.nanmean(others) < 0.5:
-                flagged.append(uid)
-        return ReduceAuditResult(epoch, stage, uids, agree, flagged)
+        with span("audit.reduce"):
+            uids, agree = butterfly.store_agreement(self.transport, epoch,
+                                                    stage, actor=self.actor)
+            flagged = []
+            for i, uid in enumerate(uids):
+                others = agree[i][np.arange(len(uids)) != i]
+                if others.size and np.nanmean(others) < 0.5:
+                    flagged.append(uid)
+            return ReduceAuditResult(epoch, stage, uids, agree, flagged)
 
     def replay_reduce(self, miner: Miner) -> tuple[int, int, float]:
         """Replay ``miner``'s logged reduce work: recompute each masked

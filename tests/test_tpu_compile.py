@@ -7,12 +7,16 @@ lowers.  Each test compiles one kernel at the shapes of ``chip_smoke.py``'s
 phases (the paper's iota-bottleneck-1.5b: d_model 2048, 32 q / 8 kv heads,
 head_dim 64, a 32-wide bottleneck, one stage's ~94M-element weight vector)
 for one chip of a described ``v5e:2x2`` topology, with no chip attached,
-and asserts that the program holds the kernel (``tpu_custom_call``).
+and asserts that the program holds the kernel (``tpu_custom_call``).  The
+kernels of the swarm's main path carry a stable name (``pallas_call``'s
+``name=``), which the compiled program's custom call bears, so that a
+device trace names them whatever the code around them is called.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,11 +60,23 @@ def one_chip():
             os.environ.pop("TPU_LOG_DIR", None)
 
 
-def _compile_has_kernel(fn, sharding, *shapes):
-    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-            for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+_COMPILED: dict = {}
+
+
+def _compiled_text(name, sharding) -> str:
+    """HLO text of case ``name`` compiled for the described chip, once
+    per process."""
+    if name not in _COMPILED:
+        fn, *shapes = CASES[name]
+        args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+                for shape, dtype in shapes]
+        _COMPILED[name] = jax.jit(fn).lower(*args).compile().as_text()
+    return _COMPILED[name]
+
+
+def _kernel_lines(text: str) -> list:
+    return [ln for ln in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in ln]
 
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
@@ -107,7 +123,53 @@ CASES = {
 }
 
 
+# the stable name each main-path case's kernel carries
+KERNEL_NAMES = {
+    "flash_attention": "flash_attention_fwd",
+    "quantize_int8_stage_vector": "quantize_int8",
+    "dequantize_int8_stage_vector": "dequantize_int8",
+    "quantize_wire": "quantize_int8",
+    "dequantize_wire": "dequantize_int8",
+    "shard_merge_m2": "shard_merge",
+    "shard_merge_m3": "shard_merge",
+    "shard_merge_m16": "shard_merge",
+}
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_compiles_for_v5e(name, one_chip):
-    fn, *shapes = CASES[name]
-    _compile_has_kernel(fn, one_chip, *shapes)
+    assert "tpu_custom_call" in _compiled_text(name, one_chip)
+
+
+@pytest.mark.parametrize("name", sorted(KERNEL_NAMES))
+def test_kernel_carries_its_name(name, one_chip):
+    """The custom call is named after the kernel (``%<name>.<n> = ...``)."""
+    lines = _kernel_lines(_compiled_text(name, one_chip))
+    assert lines
+    assert all(ln.lstrip().removeprefix("ROOT ").startswith(
+        "%" + KERNEL_NAMES[name] + ".") for ln in lines), lines
+
+
+def test_named_flash_kernel_is_found_as_the_roofline_reader_looks(one_chip):
+    """``bench/metrics/flash_attention_roofline.swarm.py`` finds the flash
+    forward as an op whose text holds ``closed_call`` or
+    ``tpu_custom_call`` and its head-major output bf16[B, H, S, D]."""
+    out = "= bf16[{},{},{},{}]".format(B, H, S, HD)
+    found = [ln for ln in _kernel_lines(_compiled_text("flash_attention",
+                                                        one_chip))
+             if ("closed_call" in ln or "tpu_custom_call" in ln)
+             and out in ln]
+    assert len(found) == 1 and "flash_attention_fwd" in found[0]
+
+
+def test_named_quantize_kernel_is_found_as_the_roofline_reader_looks(
+        one_chip):
+    """``bench/metrics/quantize_int8_roofline.swarm.py`` finds the int8
+    quantize as a ``tpu_custom_call`` op whose output tuple starts
+    ``= (s8[rows, 128]``, and reads the vector's length from it."""
+    codes = re.compile(r"= \(s8\[(\d+),(\d+)\]")
+    found = [ln for ln in _kernel_lines(_compiled_text(
+        "quantize_int8_stage_vector", one_chip)) if codes.search(ln)]
+    assert len(found) == 1 and "quantize_int8" in found[0]
+    rows, width = codes.search(found[0]).groups()
+    assert int(rows) * int(width) == STAGE_VECTOR
