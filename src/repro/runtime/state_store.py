@@ -3,7 +3,10 @@
 All miner/validator/orchestrator traffic flows through here, which is what
 makes interactions auditable ('making it easy to trace the movement of
 information').  In-process dict with:
-  * content digests (tamper evidence for validators),
+  * content digests (tamper evidence for validators): SHA-256 over each
+    leaf's raw bytes, leaves in ``tree_leaves`` order, truncated to its
+    first 96 bits (24 hex characters).  Every transport and the socket
+    server call the one ``_digest``, so both ends of a wire agree;
   * byte accounting per (namespace, direction) — the §5.3 transfer-analysis
     benchmark reads these counters,
   * optional wire codec applied on put (compressed sharing stage).
@@ -11,6 +14,7 @@ information').  In-process dict with:
 from __future__ import annotations
 
 import dataclasses
+import hashlib
 from collections import defaultdict
 from typing import Any, Optional
 
@@ -63,11 +67,18 @@ def _nbytes(value: Any) -> int:
 
 
 def _digest(value: Any) -> str:
-    import hashlib
-    h = hashlib.blake2b(digest_size=12)
+    """Hash each leaf's host buffer in place: no ``tobytes`` copy, and
+    SHA-256 runs on the CPU's hash unit where it has one.  The uint8 view
+    is what lets ``hashlib`` read dtypes the buffer protocol refuses
+    (bfloat16); ``reshape(-1)`` covers 0-d leaves.  An object leaf (the
+    in-process store takes payloads serde cannot encode), which no view
+    can read, is hashed by its ``tobytes``."""
+    h = hashlib.sha256()
     for leaf in jax.tree_util.tree_leaves(value):
-        h.update(np.ascontiguousarray(np.asarray(leaf)).tobytes())
-    return h.hexdigest()
+        arr = np.ascontiguousarray(np.asarray(leaf))
+        h.update(arr.tobytes() if arr.dtype.hasobject
+                 else arr.reshape(-1).view(np.uint8))
+    return h.hexdigest()[:24]
 
 
 class StateStore:
@@ -111,7 +122,7 @@ class StateStore:
             # host, and waits for the program that produces it
             with span("store.copy"):
                 nbytes = _nbytes(value)
-            with span("store.hash"):
+            with span("store.hash", bytes=nbytes):
                 digest = _digest(value)
             entry = StoreEntry(value, nbytes, digest,
                                dict(meta or {}, codec=codec or "none"))

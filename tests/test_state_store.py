@@ -10,9 +10,17 @@ building the socket transport, where store hygiene is load-bearing):
     runs grew the store without bound;
   * ``ValidationPhase`` KeyError'd on a miner registered mid-epoch (no
     epoch-start snapshot to replay from).
-"""
-import dataclasses
 
+Also the store's content digest against a plain reference, and the size
+its hash span carries.
+"""
+import contextlib
+import dataclasses
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -28,7 +36,8 @@ from repro.api import (
 )
 from repro.api.phases import EpochState
 from repro.configs import get, smoke_variant
-from repro.runtime import StateStore
+from repro.core import compression
+from repro.runtime import StateStore, state_store
 
 
 def _mcfg(n_layers=2):
@@ -220,3 +229,63 @@ def test_async_join_scenario_full_timeline():
     assert np.isfinite(stats[-1].mean_loss)
     # it participated in training after its first full sync
     assert swarm.miners[uid].batches_done > 0
+
+
+# ---------------------------------------------------------------------------
+# content digest: SHA-256 of each leaf's raw bytes, in leaf order, 96 bits
+# ---------------------------------------------------------------------------
+
+def _reference_digest(tree) -> str:
+    raw = b"".join(np.ascontiguousarray(np.asarray(leaf)).tobytes()
+                   for leaf in jax.tree_util.tree_leaves(tree))
+    return hashlib.sha256(raw).hexdigest()[:24]
+
+
+_F32 = np.arange(24, dtype=np.float32).reshape(4, 6) / 7
+
+_DIGEST_CASES = {
+    "f32": lambda: _F32,
+    "transposed": lambda: _F32.T,
+    "bf16": lambda: (_F32 * 3).astype(ml_dtypes.bfloat16),
+    "scalar": lambda: np.float32(2.5),
+    "device": lambda: jnp.asarray(_F32) + 1,
+    "int8_payload": lambda: compression.encode(
+        jnp.linspace(-1.0, 1.0, 300, dtype=jnp.float32), "int8"),
+    "tree": lambda: {"w": _F32, "b": np.ones(3, np.int32),
+                     "meta": (np.float32(1), np.zeros((2, 0), np.float32))},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_DIGEST_CASES))
+def test_digest_matches_plain_reference(case):
+    tree = _DIGEST_CASES[case]()
+    got = state_store._digest(tree)
+    assert got == _reference_digest(tree)
+    assert len(got) == 24 and int(got, 16) >= 0
+    if case == "transposed":
+        assert got == state_store._digest(np.ascontiguousarray(tree))
+    # one byte flipped in the largest leaf changes the digest
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    i = max(range(len(leaves)), key=lambda j: np.asarray(leaves[j]).nbytes)
+    flipped = np.array(leaves[i], order="C")
+    flipped.reshape(-1).view(np.uint8)[0] ^= 1
+    leaves[i] = flipped
+    assert state_store._digest(
+        jax.tree_util.tree_unflatten(treedef, leaves)) != got
+
+
+@pytest.mark.parametrize("codec", [None, "int8"])
+def test_hash_span_carries_the_put_size(monkeypatch, codec):
+    opened = []
+
+    def record(name, **counts):
+        opened.append((name, counts))
+        return contextlib.nullcontext()
+
+    monkeypatch.setattr(state_store, "span", record)
+    entry = StateStore().put("weights/ep0/s0/m0",
+                             np.linspace(0, 1, 1000, dtype=np.float32),
+                             codec=codec)
+    assert ("store.hash", {"bytes": entry.nbytes}) in opened
+    # the size hashed is the stored payload's: int8 codes, not 4000 f32 bytes
+    assert (entry.nbytes == 4000) == (codec is None)
