@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.flatten_util import ravel_pytree
 
+from bench import models
 from bench.lib import compare, flops
 from bench.lib import reference as ref
 from bench.lib.spans import Spans
@@ -149,16 +150,6 @@ class HookedBatches(TokenBatches):
         return super().batch(step)
 
 
-def model_config(name: str, m: dict):
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        arch_id=name, family="dense", n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=m["num_attention_heads"],
-        n_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], d_head=m["head_dim"],
-        rope_theta=float(m["rope_theta"]), norm_eps=float(m["norm_eps"]))
-
-
 class SwarmCell:
     end_to_end = "swarm_tokens_per_s"
 
@@ -167,11 +158,16 @@ class SwarmCell:
         self.seed = ctx["seed"]
         self.m = ctx["config"]["model"]
         self.opt = ctx["config"]["optimizer"]
+        self.family_name = ctx["config"]["family"]
+        self.family = models.get(self.family_name)
         self.t = ctx["traffic"]
         self.merges = "sync" in self.t["phases"]
         self.validates = "validation" in self.t["phases"]
         self.spans = Spans()
         self.prog: dict = {}
+        # planted faults of the check, as the reference put in the
+        # program's place
+        self.faults = {"half_batch": {"half": True}}
         assert self.t["ticks_per_epoch"] > FOLLOWED, self.t
 
     def batches(self, cls=TokenBatches):
@@ -210,14 +206,15 @@ class SwarmCell:
         phases = [TimedPhase(kinds[n](), self.spans,
                              self._keep_records if n == "training" else None)
                   for n in t["phases"]]
-        swarm = Swarm.create(model_config(self.ctx["config_name"], self.m),
-                             config, phases=phases, train_cfg=train_cfg)
+        swarm = Swarm.create(
+            self.family.program_config(self.ctx["config_name"], self.m),
+            config, phases=phases, train_cfg=train_cfg)
 
         # the benchmark's weights and batches in place of the program's
         self.shapes = [jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), a)
             for a in swarm.anchors]
-        w0 = ref.make_weights(self.seed, self.shapes)
+        w0 = ref.make_weights(self.seed, self.shapes, self.family.init_leaf)
         for s, w in enumerate(w0):
             swarm.anchors[s] = w
             swarm.outer[s] = diloco.outer_init(w)
@@ -323,8 +320,8 @@ class SwarmCell:
         tokens = (attempted - failed) * t["batch_size"] * t["seq_len"]
         epoch_s = sum(e[0] for e in epochs)
         self.readings = {
-            "tokens": tokens, "epochs": len(epochs), "epoch_seconds": epoch_s,
-            "window_seconds": window_s, "ticks": attempted - failed,
+            "tokens": tokens, "epochs": len(epochs), "trained_seconds": epoch_s,
+            "ticks": attempted - failed,
             "store_bytes": swarm.transport.traffic_report()["total_bytes"]
             - bytes0,
         }
@@ -339,12 +336,10 @@ class SwarmCell:
             self.readings, spans=self.spans.rows,
             window_start_ns=self.window_start_ns, model=m,
             flops_per_token=flops.train_flops_per_token(
-                m, n_layers, t["seq_len"], t["bottleneck_dim"],
+                m, self.family, n_layers, t["seq_len"], t["bottleneck_dim"],
                 t["n_stages"] - 1),
-            attention=dict(batch=t["batch_size"], seq=t["seq_len"],
-                           heads=m["num_attention_heads"],
-                           kv_heads=m["num_key_value_heads"],
-                           head_dim=m["head_dim"]),
+            attention=self.family.attention_shape(m, t["batch_size"],
+                                                  t["seq_len"]),
             stage_vector_len=self.vector_len)
 
     # ------------------------------------------------------------------
@@ -385,15 +380,18 @@ class SwarmCell:
                 if checked else float("nan")
         return out
 
-    def reference(self, mode: str, routing: list, half: bool = False) -> dict:
-        """Follow ``routing`` (one tuple of miner uids per tick) with the
-        plain reference in ``mode``: the first three ticks for the losses,
-        gradients and changes, and, where the timeline merges, the whole
-        epoch and its merge.  ``half`` is a planted fault: every tick
-        trains on the first half of its batch only."""
+    def reference(self, mode: str, routing: list | None = None,
+                  half: bool = False) -> dict:
+        """Follow ``routing`` (one tuple of miner uids per tick; the set-up
+        epoch's by default) with the plain reference in ``mode``: the first
+        three ticks for the losses, gradients and changes, and, where the
+        timeline merges, the whole epoch and its merge.  ``half`` is a
+        planted fault: every tick trains on the first half of its batch
+        only."""
         t, opt = self.t, self.opt
+        routing = self.routing if routing is None else routing
         stage_of = self.stage_of
-        w0 = ref.make_weights(self.seed, self.shapes)
+        w0 = ref.make_weights(self.seed, self.shapes, self.family.init_leaf)
         params = {u: w0[s] for u, s in stage_of.items()}
         states = {u: ref.adamw_init(w0[s]) for u, s in stage_of.items()}
         corpus = self.batches()
@@ -408,7 +406,8 @@ class SwarmCell:
             loss, ps, ss = ref.train_tick(
                 tuple(params[u] for u in uids), tuple(states[u] for u in uids),
                 jnp.asarray(b["tokens"]), jnp.asarray(b["labels"]),
-                m=mkey, opt=okey, mode=mode, trained=(True,) * len(uids))
+                m=mkey, opt=okey, mode=mode, trained=(True,) * len(uids),
+                family=self.family_name)
             for u, p, s in zip(uids, ps, ss):
                 params[u], states[u] = p, s
             if tick < FOLLOWED:

@@ -16,27 +16,16 @@ Conventions, fixed here so that every PR counts alike:
 from __future__ import annotations
 
 
-def matmul_params_per_token(m: dict, n_layers: int, bottleneck_dim: int,
-                            n_boundaries: int) -> int:
-    """Matrix-product weights a token passes through: the blocks, the
-    unembedding over the real vocabulary, and one bottleneck encode and
-    decode per stage boundary."""
-    d, H, KH, D = (m["hidden_size"], m["num_attention_heads"],
-                   m["num_key_value_heads"], m["head_dim"])
-    attn = d * H * D * 2 + d * KH * D * 2              # wq, wo, wk, wv
-    ffn = 3 * d * m["intermediate_size"]               # gate, up, out
-    boundary = 2 * d * bottleneck_dim                  # w_down + w_up
-    return (n_layers * (attn + ffn) + m["vocab_size"] * d
-            + n_boundaries * boundary)
-
-
-def train_flops_per_token(m: dict, n_layers: int, seq_len: int,
+def train_flops_per_token(m: dict, family, n_layers: int, seq_len: int,
                           bottleneck_dim: int, n_boundaries: int) -> float:
-    H, D = m["num_attention_heads"], m["head_dim"]
-    dense = 6 * matmul_params_per_token(m, n_layers, bottleneck_dim,
-                                        n_boundaries)
-    attn = 6 * seq_len * H * D * n_layers
-    return float(dense + attn)
+    """Model FLOPs per trained token: the family's layers
+    (``block_flops_per_token`` of its module), the unembedding over the
+    real vocabulary, and one bottleneck encode and decode per stage
+    boundary."""
+    d = m["hidden_size"]
+    shared = m["vocab_size"] * d + n_boundaries * 2 * d * bottleneck_dim
+    return float(family.block_flops_per_token(m, n_layers, seq_len)
+                 + 6 * shared)
 
 
 def flash_forward(batch: int, seq: int, heads: int, kv_heads: int,
@@ -62,3 +51,48 @@ def least_seconds(ops: float, nbytes: float, peaks: dict) -> tuple[float, str]:
     t_c = ops / peaks["bf16_flops"]
     t_m = nbytes / peaks["hbm_bytes_per_s"]
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
+
+
+# ---------------------------------------------------------------------------
+# the bodies of the readers that every cell kind shares (``bench/metrics``);
+# ``r`` is ``run.Readings``
+# ---------------------------------------------------------------------------
+
+
+def mfu(r):
+    """Model FLOP utilization of the whole step, in %: the model FLOPs of
+    the tokens the driver counts as trained (``tokens``) over the host time
+    they took (``trained_seconds``), the chips and the bf16 peak."""
+    ctx = r.ctx
+    if not ctx["tokens"] or not ctx["trained_seconds"]:
+        return None
+    done = ctx["tokens"] * ctx["flops_per_token"]
+    return 100.0 * done / (ctx["trained_seconds"] * r.chips
+                           * r.peaks["bf16_flops"])
+
+
+def flash_roofline(r):
+    """The Pallas flash-attention forward's share of its roofline, in %:
+    the least time of its causal operations and bytes at the driver's
+    attention shape (``attention``), over the time each call took in the
+    trace, summed over calls and chips.  A call is one op named
+    ``closed_call`` (the ``custom_vjp`` body it lowers from) or
+    ``tpu_custom_call``, with the head-major output
+    bf16[batch, heads, seq, head_dim]."""
+    from bench.lib import trace as tr
+    a = r.ctx["attention"]
+    out = "= bf16[{},{},{},{}]".format(a["batch"], a["heads"], a["seq"],
+                                       a["head_dim"])
+
+    def match(name):
+        return ("closed_call" in name or "tpu_custom_call" in name) \
+            and out in name
+
+    rows = tr.events(r.trace, "ops", match, r.lo, r.hi)
+    if not rows:
+        return None
+    ops, nbytes = flash_forward(a["batch"], a["seq"], a["heads"],
+                                a["kv_heads"], a["head_dim"])
+    least, _ = least_seconds(ops, nbytes, r.peaks)
+    took = sum(row[2] for _, row in rows) / 1e9
+    return 100.0 * least * len(rows) / took

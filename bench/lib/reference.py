@@ -1,7 +1,11 @@
-"""Plain float32 reference of the decoder stages the benchmark trains.
+"""Plain float32 reference of the model stages the benchmark trains: the
+parts every model family shares.
 
 Written from the published architecture and the configuration file alone:
-it imports nothing of the program under test.  It works on parameter trees
+it imports nothing of the program under test.  A stage is the family's
+layers (``bench/models/<family>.py`` ``blocks``) between IOTA's entry and
+exit, which every family has: the embedding or the bottleneck decode in,
+the bottleneck encode or the head out.  It works on parameter trees
 of the program's layout (leaf names and shapes), which the benchmark fills
 itself from the seed (``make_weights``), so both sides start from the same
 numbers without the reference reading anything the program made.
@@ -15,11 +19,11 @@ rounded to float8_e5m2 before the products that carry it back.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+from bench import models
 
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3 = jnp.float8_e4m3fn          # fp8 values and weights
@@ -31,45 +35,52 @@ E5M2 = jnp.float8_e5m2            # fp8 gradients
 # ---------------------------------------------------------------------------
 
 
-def _leaf_name(path) -> str:
+def leaf_name(path) -> str:
     return "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
 
 
-def _init_leaf(key, name: str, shape, dtype):
-    last = name.rsplit("/", 1)[-1]
-    if "norm" in last:
-        return jnp.ones(shape, dtype)
-    if len(shape) == 0:
-        return jnp.full(shape, 0.5, dtype)          # the decode gate alpha
-    if last == "embed":
-        scale = 1.0
-    elif last == "unembed":
-        scale = 1.0 / math.sqrt(shape[-1])
-    else:
-        scale = 1.0 / math.sqrt(shape[-2])
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
-
-
-def make_weights(seed: int, shapes: list) -> list:
-    """Every stage's weights, of the trees ``shapes`` (ShapeDtypeStructs),
-    made on the device in one jitted call from ``seed``: leaf i of stage s
-    draws from the key (seed, s, i)."""
+def weight_maker(shapes: list, init_leaf, shardings=None):
+    """``make(seed)``: every stage's weights, of the trees ``shapes``
+    (ShapeDtypeStructs), made on the device in one jitted call from
+    ``seed``: leaf i of stage s draws from the key (seed, s, i), by the
+    family's ``init_leaf``.  ``shardings``, where given, is a tree of
+    shardings per stage tree: the weights are made in place there.
+    ``make.trees(key)`` is the same from ``key``, to call inside another
+    jitted function."""
     flat = [jax.tree_util.tree_flatten_with_path(t) for t in shapes]
-    plan = [[(_leaf_name(p), tuple(x.shape), x.dtype) for p, x in leaves]
+    plan = [[(leaf_name(p), tuple(x.shape), x.dtype) for p, x in leaves]
             for leaves, _ in flat]
 
-    @jax.jit
-    def make(key):
+    def draw(key):
         out = []
         for stage, leaves in enumerate(plan):
             k = jax.random.fold_in(key, stage)
-            out.append([_init_leaf(jax.random.fold_in(k, i), n, shp, dt)
+            out.append([init_leaf(jax.random.fold_in(k, i), n, shp, dt)
                         for i, (n, shp, dt) in enumerate(leaves)])
         return out
 
-    made = make(jax.random.key(seed % 2**32))
-    return [jax.tree_util.tree_unflatten(treedef, leaves)
-            for (_, treedef), leaves in zip(flat, made)]
+    draw = jax.jit(draw) if shardings is None else jax.jit(
+        draw, out_shardings=[jax.tree.leaves(s) for s in shardings])
+
+    def trees(key) -> list:
+        return [jax.tree_util.tree_unflatten(treedef, leaves)
+                for (_, treedef), leaves in zip(flat, draw(key))]
+
+    def make(seed: int) -> list:
+        return trees(seed_key(seed))
+
+    make.trees = trees
+    return make
+
+
+def seed_key(seed: int):
+    """The key the weights of ``seed`` are drawn from."""
+    return jax.random.key(seed % 2**32)
+
+
+def make_weights(seed: int, shapes: list, init_leaf, shardings=None) -> list:
+    """``weight_maker``'s weights for ``seed``."""
+    return weight_maker(shapes, init_leaf, shardings)(seed)
 
 
 # ---------------------------------------------------------------------------
@@ -116,60 +127,13 @@ def einsum(spec: str, a, b, mode: str):
 
 
 # ---------------------------------------------------------------------------
-# the decoder block (pre-norm attention + SwiGLU, RMSNorm, rotary)
+# the norm every family shares
 # ---------------------------------------------------------------------------
 
 
 def rmsnorm(x, gamma, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
         * gamma
-
-
-def rotary(x, theta: float):
-    """Rotary position embedding over the whole head, split-half pairing:
-    dimension i rotates with i + D/2 at frequency theta^(-i / (D/2))."""
-    S, D = x.shape[1], x.shape[-1]
-    half = D // 2
-    inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv        # (S, D/2)
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def attention(p, x, m, mode):
-    B, S, _ = x.shape
-    H, KH, D = m["num_attention_heads"], m["num_key_value_heads"], \
-        m["head_dim"]
-    G = H // KH
-    q = einsum("bsd,de->bse", x, p["wq"], mode).reshape(B, S, KH, G, D)
-    k = einsum("bsd,de->bse", x, p["wk"], mode).reshape(B, S, KH, D)
-    v = einsum("bsd,de->bse", x, p["wv"], mode).reshape(B, S, KH, D)
-    q = rotary(q.reshape(B, S, H, D), m["rope_theta"]).reshape(B, S, KH, G, D)
-    k = rotary(k, m["rope_theta"])
-    s = einsum("bqkgd,bskd->bkgqs", q, k, mode) / math.sqrt(D)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal, s, -jnp.inf)
-    w = jax.nn.softmax(s, axis=-1)
-    o = einsum("bkgqs,bskd->bqkgd", w, v, mode).reshape(B, S, H * D)
-    return einsum("bse,ed->bsd", o, p["wo"], mode)
-
-
-def mlp(p, x, mode):
-    h = jax.nn.silu(einsum("bsd,df->bsf", x, p["w_gate"], mode)) \
-        * einsum("bsd,df->bsf", x, p["w_up"], mode)
-    return einsum("bsf,fd->bsd", h, p["w_out"], mode)
-
-
-def blocks(pb, x, m, mode):
-    eps = m["norm_eps"]
-    n_layers = jax.tree.leaves(pb)[0].shape[0]
-    for layer in range(n_layers):
-        p = jax.tree.map(lambda a: a[layer], pb)
-        x = x + attention(p["attn"], rmsnorm(x, p["attn_norm"], eps), m, mode)
-        x = x + mlp(p["mlp"], rmsnorm(x, p["ffn_norm"], eps), mode)
-    return x
 
 
 # ---------------------------------------------------------------------------
@@ -196,19 +160,32 @@ def stage_out(p, x, m, mode):
     return einsum("bsd,vd->bsv", x, table, mode)
 
 
-def stage(p, x_in, m, mode):
+def stage(p, x_in, m, mode, family: str, first: int = 0):
+    """One stage of the family ``family`` (``bench.models.get``), whose
+    first layer is the model's layer ``first``."""
+    blocks = models.get(family).blocks
     return stage_out(p, blocks(p["blocks"], stage_in(p, x_in, m, mode), m,
-                               mode), m, mode)
+                               mode, first), m, mode)
 
 
-def loss_fn(stage_params: list, tokens, labels, m, mode):
+def token_loss(logits, labels, z_loss: float = 0.0):
+    """Mean next-token cross entropy, with ``z_loss`` x logsumexp^2 added
+    to each token's where the objective has that penalty."""
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    true = jnp.take_along_axis(logits, labels[..., None], axis=-1)[..., 0]
+    nll = lse - true
+    if z_loss:
+        nll = nll + z_loss * jnp.square(lse)
+    return jnp.mean(nll)
+
+
+def loss_fn(stage_params: list, tokens, labels, m, mode, family: str):
     """Mean next-token cross entropy through every stage in order."""
-    x = tokens
+    x, first = tokens, 0
     for p in stage_params:
-        x = stage(p, x, m, mode)
-    lse = jax.nn.logsumexp(x, axis=-1)
-    true = jnp.take_along_axis(x, labels[..., None], axis=-1)[..., 0]
-    return jnp.mean(lse - true)
+        x = stage(p, x, m, mode, family, first)
+        first += jax.tree.leaves(p["blocks"])[0].shape[0]
+    return token_loss(x, labels)
 
 
 # ---------------------------------------------------------------------------
@@ -249,15 +226,17 @@ def adamw_update(grads, state, params, opt):
     return new, {"mu": mu, "nu": nu, "step": step}
 
 
-@functools.partial(jax.jit, static_argnames=("m", "opt", "mode", "trained"))
+@functools.partial(jax.jit, static_argnames=("m", "opt", "mode", "trained",
+                                             "family"))
 def train_tick(params: tuple, states: tuple, tokens, labels, *, m, opt, mode,
-               trained: tuple):
+               trained: tuple, family: str):
     """One pathway through the stages: loss, gradients, then an AdamW update
     of each stage whose flag in ``trained`` is set.  ``m`` and ``opt`` are
     hashable (tuple-of-items) views of the configuration."""
     m, opt = dict(m), dict(opt)
     loss, grads = jax.value_and_grad(
-        lambda ps: loss_fn(list(ps), tokens, labels, m, mode))(tuple(params))
+        lambda ps: loss_fn(list(ps), tokens, labels, m, mode, family))(
+            tuple(params))
     out_p, out_s = [], []
     for p, s, g, on in zip(params, states, grads, trained):
         if on:

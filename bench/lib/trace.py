@@ -6,7 +6,11 @@ clock in nanoseconds:
   devices  per accelerator: its "XLA Ops" events (name, start, duration,
            and the HLO text of the op where the trace gives it) and its
            "XLA Modules" events (one per launched program)
-  host     the benchmark's own spans, ``bench.*`` TraceAnnotations
+  host     the benchmark's own spans, ``bench.*`` TraceAnnotations, under
+           their name without the prefix (``window``, ``epoch``), and the
+           program's, ``iota.*``, under their full name
+           (``iota.store.hash``): [name, start, duration], with a fourth
+           element, the span's ``bytes``, where it carries them
 
 The reducers below work on that compact form, so a small recorded trace can
 check them on the CPU (``bench/tests``).
@@ -17,8 +21,12 @@ import glob
 import gzip
 import json
 import os
+import re
 
 SPAN_PREFIX = "bench."
+PROGRAM_PREFIX = "iota."
+# the opcode of a control-flow op in its HLO text, after the output shape
+CONTROL = re.compile(r"[)}\]] (?:while|conditional|call)\(")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 
@@ -33,6 +41,21 @@ def _stat(event, key):
 def is_chip(plane_name: str) -> bool:
     return plane_name.startswith("/device:") \
         and not plane_name.startswith("/device:CUSTOM")
+
+
+def host_span(event):
+    """The row ``load`` keeps of a host event, or None."""
+    if event.name.startswith(SPAN_PREFIX):
+        name = event.name[len(SPAN_PREFIX):]
+    elif event.name.startswith(PROGRAM_PREFIX):
+        name = event.name
+    else:
+        return None
+    row = [name, float(event.start_ns), float(event.duration_ns)]
+    nbytes = _stat(event, "bytes")
+    if nbytes is not None:
+        row.append(float(nbytes))
+    return row
 
 
 def load(log_dir: str) -> dict:
@@ -63,9 +86,9 @@ def load(log_dir: str) -> dict:
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
                 for e in ln.events:
-                    if e.name.startswith(SPAN_PREFIX):
-                        host.append([e.name[len(SPAN_PREFIX):],
-                                     float(e.start_ns), float(e.duration_ns)])
+                    row = host_span(e)
+                    if row is not None:
+                        host.append(row)
     return {"devices": devices, "host": host, "lines": lines}
 
 
@@ -110,8 +133,12 @@ def clip(intervals, lo, hi) -> list:
 
 
 def busy(trace: dict, device: str, lo: float, hi: float) -> list:
-    """Disjoint intervals in [lo, hi) in which an op ran on ``device``."""
-    ops = trace["devices"][device]["ops"]
+    """Disjoint intervals in [lo, hi) in which an op ran on ``device``.
+    The control-flow ops (``while``, ``conditional``, ``call``), whose
+    events span the ops of their bodies and the gaps between them, are not
+    counted: the ops inside them are."""
+    ops = [r for r in trace["devices"][device]["ops"]
+           if not CONTROL.search(r[0])]
     return union(clip([[r[1], r[1] + r[2]] for r in ops], lo, hi))
 
 
@@ -121,6 +148,47 @@ def busy_seconds(trace: dict, lo: float, hi: float) -> float:
     if not devs:
         return 0.0
     tot = sum(e - s for d in devs for s, e in busy(trace, d, lo, hi))
+    return tot / len(devs) / 1e9
+
+
+def idle_share(trace: dict, lo: float, hi: float):
+    """Share of [lo, hi), in %, in which no op ran on a chip (``busy``),
+    averaged over the chips; None where there is nothing to read."""
+    if hi <= lo or not trace["devices"]:
+        return None
+    return 100.0 * (1.0 - busy_seconds(trace, lo, hi) / ((hi - lo) / 1e9))
+
+
+def overlap(a: list, b: list) -> float:
+    """Length of the intersection of two sorted, disjoint interval lists."""
+    i = j = 0
+    tot = 0.0
+    while i < len(a) and j < len(b):
+        tot += max(0.0, min(a[i][1], b[j][1]) - max(a[i][0], b[j][0]))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return tot
+
+
+def alone_seconds(trace: dict, match, lo: float, hi: float) -> float:
+    """Time in [lo, hi) in which an op whose name satisfies ``match`` runs
+    on a device and no other op does, averaged over the devices.  The
+    control-flow ops (``while``, ``conditional``, ``call``), whose events
+    span the ops of their bodies, count as neither."""
+    devs = sorted(trace["devices"])
+    if not devs:
+        return 0.0
+    tot = 0.0
+    for d in devs:
+        ops = [r for r in trace["devices"][d]["ops"]
+               if not CONTROL.search(r[0])]
+        mine = union(clip([[r[1], r[1] + r[2]] for r in ops if match(r[0])],
+                          lo, hi))
+        rest = union(clip([[r[1], r[1] + r[2]] for r in ops
+                           if not match(r[0])], lo, hi))
+        tot += sum(e - s for s, e in mine) - overlap(mine, rest)
     return tot / len(devs) / 1e9
 
 
@@ -139,7 +207,7 @@ def gaps(trace: dict, device: str, lo: float, hi: float) -> list:
 def span_at(trace: dict, t: float, skip=("window",)) -> str:
     """Name of the innermost host span covering time ``t``."""
     best, best_len = "outside any span", float("inf")
-    for name, s, d in trace["host"]:
+    for name, s, d, *_ in trace["host"]:
         if name in skip:
             continue
         if s <= t < s + d and d < best_len:

@@ -1,12 +1,7 @@
-"""Model FLOP utilization of the swarm's whole epoch: the model FLOPs of
-the tokens trained in the window (``bench/lib/flops.py``'s convention, no
-recomputed work) over the epochs' time, the chips and the bf16 peak."""
+"""Model FLOP utilization of the swarm's whole epochs (``flops.mfu``): the
+tokens of the window's epochs over those epochs' time."""
 
 
 def read(r):
-    ctx = r.ctx
-    if not ctx["tokens"] or not ctx["epoch_seconds"]:
-        return None
-    done = ctx["tokens"] * ctx["flops_per_token"]
-    return 100.0 * done / (ctx["epoch_seconds"] * r.chips
-                           * r.peaks["bf16_flops"])
+    from bench.lib import flops
+    return flops.mfu(r)

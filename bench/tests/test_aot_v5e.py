@@ -60,12 +60,13 @@ def load(rel):
 def test_stage_programs_compile_for_v5e(one_chip, traffic, chip_kernels):
     import jax
     import jax.numpy as jnp
-    from bench.drivers.swarm import model_config
+    from bench import models
     from repro.runtime import stage_model as sm
 
     cfg = load("bench/configs/stablelm-3b-swarm.json")
     t = load(f"bench/traffic/{traffic}.json")
-    spec = sm.SwarmModelSpec(model_config(cfg["name"], cfg["model"]),
+    family = models.get(cfg["family"])
+    spec = sm.SwarmModelSpec(family.program_config(cfg["name"], cfg["model"]),
                              t["n_stages"], True, t["bottleneck_dim"])
     B, S, db = t["batch_size"], t["seq_len"], t["bottleneck_dim"]
 
@@ -98,26 +99,47 @@ def test_stage_programs_compile_for_v5e(one_chip, traffic, chip_kernels):
             assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_pipeline_step_fits_v5e_2x2(topo, chip_kernels):
-    """The pipeline driver's whole step (1f1b over four stages, flash
-    kernel and fused boundary codecs, the hand-offs between chips) at the
-    widths of ``glm4-9b-pipe4``: the compiler that refuses a step that does
-    not fit a chip's memory accepts this one."""
+def compile_pipeline_step(topo, **change):
+    """The pipeline driver's step at ``glm4-9b-pipe4``'s sizes, with the
+    model keys ``change`` sets, compiled for the 2x2 host."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
+    from bench import models
     from bench.drivers import pipeline as drv
 
     cfg = load("bench/configs/glm4-9b-pipe4.json")
     t = load("bench/traffic/pipe4_1f1b.json")
     mesh = drv.make_mesh(topo.devices, t)
-    init, shardings, step = drv.build_step(cfg["name"], cfg["model"], t, mesh)
+    shapes, shardings, step = drv.build_step(
+        models.get(cfg["family"]), cfg["name"], dict(cfg["model"], **change),
+        t, mesh, cfg["objective"]["z_loss"])
     params = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
-        jax.eval_shape(init, jax.random.key(0)), shardings)
+        shapes, shardings)
     rows = jax.ShapeDtypeStruct((t["batch_size"], t["seq_len"]), jnp.int32,
                                 sharding=NamedSharding(mesh, PartitionSpec()))
-    compiled = step.lower(params, {"tokens": rows, "labels": rows}).compile()
-    text = compiled.as_text()
+    return step.lower(params, {"tokens": rows, "labels": rows}).compile()
+
+
+def test_pipeline_step_fits_v5e_2x2(topo, chip_kernels):
+    """The pipeline driver's whole step (1f1b over four stages, flash
+    kernel and fused boundary codecs, the hand-offs between chips) at the
+    widths of ``glm4-9b-pipe4``: the compiler that refuses a step that does
+    not fit a chip's memory accepts this one."""
+    text = compile_pipeline_step(topo).as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+@pytest.mark.parametrize("change", [
+    {"num_hidden_layers": 16},                         # 4 layers a stage
+    {"num_hidden_layers": 4, "vocab_size": 151552},    # published vocabulary
+], ids=["one_more_layer_a_stage", "published_vocabulary_at_one_layer"])
+def test_pipeline_cut_is_what_the_chips_force(topo, chip_kernels, change):
+    """``glm4-9b-pipe4``'s cut: one more layer a stage, or the published
+    vocabulary at even one layer a stage, does not fit a chip."""
+    import jax
+    with pytest.raises(jax.errors.JaxRuntimeError,
+                       match="Ran out of memory in memory space hbm"):
+        compile_pipeline_step(topo, **change)

@@ -120,3 +120,167 @@ def test_kernel_events_are_found_by_name(small):
             if flash(r[0]) and r[1] >= lo and r[1] + r[2] <= hi]
     assert len(rows) == len(want) > 0
     assert np.all([r[2] > 0 for _, r in rows])
+
+
+# ---------------------------------------------------------------------------
+# the program's spans, and the pipeline's readers on a synthetic trace
+# ---------------------------------------------------------------------------
+
+
+def test_load_keeps_program_spans_with_their_bytes(tmp_path):
+    """A profile taken here on the CPU, with spans of both kinds: the
+    benchmark's keep their short name, the program's their full one and
+    their ``bytes``; spans of neither kind are dropped."""
+    import jax
+    import jax.numpy as jnp
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.window"):
+        with jax.profiler.TraceAnnotation("iota.tick"):
+            with jax.profiler.TraceAnnotation("iota.store.hash",
+                                              bytes=123456):
+                jnp.ones(8).block_until_ready()
+        with jax.profiler.TraceAnnotation("elsewhere.span"):
+            pass
+    jax.profiler.stop_trace()
+    t = tr.load(str(tmp_path))
+    rows = {r[0]: r for r in t["host"]}
+    assert set(rows) == {"window", "iota.tick", "iota.store.hash"}
+    assert rows["iota.store.hash"][3] == 123456.0
+    assert len(rows["iota.tick"]) == len(rows["window"]) == 3
+    lo, hi = tr.window_of(t)
+    assert (lo, hi) == (rows["window"][1], rows["window"][1]
+                        + rows["window"][2])
+    inner = rows["iota.store.hash"]
+    assert tr.span_at(t, inner[1] + inner[2] / 2) == "iota.store.hash"
+
+
+def test_program_spans_change_no_reduction(small):
+    """The recorded trace with program spans added (one carrying bytes):
+    the window and every device reduction read as before, and a gap
+    inside a program span is named by it."""
+    t, lo, hi = small
+    dev = sorted(t["devices"])[0]
+    gap = max(tr.gaps(t, dev, lo, hi), key=lambda g: g[1] - g[0])
+    more = dict(t, host=t["host"] + [
+        ["iota.store.copy", gap[0], gap[1] - gap[0], 4096.0],
+        ["iota.tick", lo, hi - lo]])
+    assert tr.window_of(more) == (lo, hi)
+    assert tr.busy_seconds(more, lo, hi) == tr.busy_seconds(t, lo, hi)
+    assert tr.module_seconds(more, lo, hi) == tr.module_seconds(t, lo, hi)
+    assert [g[1] for g in tr.idle_gaps(more, lo, hi)] == \
+        [g[1] for g in tr.idle_gaps(t, lo, hi)]
+    assert tr.idle_gaps(more, lo, hi, top=1)[0][0] == "iota.store.copy"
+
+
+def four_chip_trace(seed: int = 0):
+    """Four chips' ops over a window of 1 ms: compute fusions, and the
+    collectives of a pipeline step as their HLO text names them, laid out
+    at random so that they overlap each other and the window's ends."""
+    rng = np.random.default_rng(seed)
+    names = {
+        "compute": "%fusion.7 = bf16[1,2048,4096]{2,1,0} fusion(bf16[1,2048,"
+                   "4096]{2,1,0} %p), kind=kLoop",
+        "permute": "%collective-permute-done.1 = bf16[1,2048,32]{2,1,0} "
+                   "collective-permute-done(bf16[1,2048,32]{2,1,0} "
+                   "%collective-permute-start.1)",
+        "start": "%collective-permute-start.1 = (bf16[1,2048,32]{2,1,0}, "
+                 "bf16[1,2048,32]{2,1,0}) collective-permute-start("
+                 "bf16[1,2048,32]{2,1,0} %code), source_target_pairs="
+                 "{{0,1},{1,2},{2,3}}",
+        "reduce": "%all-reduce.3 = f32[4096]{0} all-reduce(f32[4096]{0} "
+                  "%g), replica_groups={{0,1,2,3}}, to_apply=%add",
+        "named": "%copy.collective-permute.4 = f32[8]{0} copy(f32[8]{0} %x)",
+        "loop": "%while.2 = (s32[], bf16[1,2048,32]{2,1,0}) while((s32[], "
+                "bf16[1,2048,32]{2,1,0}) %tuple), condition=%cond, body=%body",
+    }
+    devices = {}
+    for d in range(4):
+        ops = []
+        for kind in rng.choice(list(names), 60):
+            s = float(rng.uniform(-1e5, 1.05e6))
+            ops.append([names[kind], s, float(rng.uniform(1e3, 6e4)), ""])
+        devices[f"/device:TPU:{d}"] = {"ops": ops, "modules": []}
+    return {"devices": devices, "host": [["window", 0.0, 1e6]], "lines": {}}
+
+
+def sweep_alone(ops, is_coll, lo, hi) -> float:
+    """Nanoseconds in which a collective runs and nothing else but a
+    control-flow op (``while``, ``conditional``), by a sweep over every op
+    boundary."""
+    ops = [r for r in ops if not r[0].startswith(("%while", "%conditional"))]
+    pts = sorted({lo, hi} | {min(max(x, lo), hi) for _, s, d, *_ in ops
+                             for x in (s, s + d)})
+    alone = 0.0
+    for a, b in zip(pts, pts[1:]):
+        mid = (a + b) / 2
+        on = [is_coll(n) for n, s, d, *_ in ops if s <= mid < s + d]
+        if on and all(on):
+            alone += b - a
+    return alone
+
+
+def read_metric(name: str, trace) -> float:
+    from bench import run
+    lo, hi = tr.window_of(trace)
+    reader = run.load_module(os.path.join(ROOT, "bench", "metrics",
+                                          name + ".py"), name)
+    return reader.read(run.Readings(trace, lo, hi, {}, {}, 4)), reader
+
+
+PIPE4 = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
+                     "trace_pipe4_slice.json.gz")
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, "recorded"])
+def test_collective_exposed_share_matches_a_sweep(seed):
+    """On synthetic traces, and on 0.4 s of a traced ``pipe4-1f1b`` window
+    recorded on a v5e 2x2 (``data/trace_pipe4_slice.json.gz``), where the
+    scan's ``while`` and the slots' ``conditional`` span the other ops."""
+    t = tr.read_saved(PIPE4) if seed == "recorded" else four_chip_trace(seed)
+    lo, hi = tr.window_of(t)
+    got, reader = read_metric("collective_exposed_share.pipeline", t)
+
+    def is_coll(name):
+        return bool(reader.OPCODE.search(name))
+
+    assert is_coll("%all-reduce.3 = f32[] all-reduce(f32[] %g)")
+    assert is_coll("%x = bf16[2]{0} collective-permute-done(bf16[2] %y)")
+    assert not is_coll("%copy.collective-permute.4 = f32[8]{0} copy(%x)")
+    want = np.mean([sweep_alone(d["ops"], is_coll, lo, hi)
+                    for d in t["devices"].values()]) / (hi - lo)
+    assert 0 < got < 100
+    assert got == pytest.approx(100 * want, rel=1e-9)
+
+
+def not_control(ops) -> list:
+    return [r for r in ops
+            if not r[0].startswith(("%while", "%conditional", "%call"))]
+
+
+@pytest.mark.parametrize("seed", [0, 1, "recorded"])
+def test_idle_share_pipeline_averages_the_chips(seed):
+    """Idle is where no op but a control-flow wrapper runs: on the
+    recorded slice the scan's ``while`` spans the whole window, and the
+    gaps between a slot's ops still count."""
+    t = tr.read_saved(PIPE4) if seed == "recorded" else four_chip_trace(seed)
+    lo, hi = tr.window_of(t)
+    got, _ = read_metric("idle_share.pipeline", t)
+    busy = np.mean([sweep_busy(not_control(d["ops"]), lo, hi)
+                    for d in t["devices"].values()])
+    assert 0 < got < 100
+    assert got == pytest.approx(100 * (1 - busy / (hi - lo)), rel=1e-9)
+    if seed == "recorded":
+        every = np.mean([sweep_busy(d["ops"], lo, hi)
+                         for d in t["devices"].values()])
+        assert every > 0.999 * (hi - lo), "the while op spans the slice"
+        assert got > 100 * (1 - every / (hi - lo))
+
+
+def test_idle_share_readers_are_one_reduction(small):
+    t, lo, hi = small
+    swarm, _ = read_metric("idle_share.swarm", t)
+    pipe, _ = read_metric("idle_share.pipeline", t)
+    assert swarm == pipe == tr.idle_share(t, lo, hi)
+    assert swarm == pytest.approx(
+        100 * (1 - sweep_busy(t["devices"][sorted(t["devices"])[0]]["ops"],
+                              lo, hi) / (hi - lo)), rel=1e-9)
