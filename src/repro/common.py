@@ -30,7 +30,8 @@ def span(name: str, **counts) -> jax.profiler.TraceAnnotation:
     device's op events, so an idle stretch of the chip can be put down to
     the host call around it.  Spans wrap host calls only, never traced
     code, and never wait on the device.  ``counts`` (``bytes=``) are the
-    span's metadata: pass only values already in hand when it opens."""
+    span's metadata: pass only values already in hand when it opens; one
+    known only when the call returns goes to the span's ``set_metadata``."""
     return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **counts)
 
 # ---------------------------------------------------------------------------

@@ -85,11 +85,9 @@ from repro.api.transport import SocketTransport
 from repro.common import cosine_similarity
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core import butterfly, compression
-from repro.optim import adamw
-from repro.optim.schedules import cosine_warmup
 from repro.runtime import stage_model as sm
 from repro.runtime.chaos import wrap_transport
-from repro.runtime.miner import Miner
+from repro.runtime.miner import Miner, inner_optimizer
 from repro.runtime.network import FaultModel, MinerBehavior
 from repro.runtime.snapshot_cache import DiskSnapshotCache
 from repro.runtime.validator import COSINE_THRESHOLD
@@ -711,16 +709,13 @@ class ValidatorActor(ActorProcess):
 
     def __init__(self, spec: ActorSpec):
         super().__init__(spec)
-        self.opt = None
+        self.update = None
 
     def setup(self) -> None:
         super().setup()
-        tc = self.spec.train_cfg
-        # the same inner optimizer Miner builds: replayed updates must
-        # track the miner's own update rule exactly
-        self.opt = adamw(cosine_warmup(tc.lr, tc.warmup_steps, 10_000),
-                         beta1=tc.beta1, beta2=tc.beta2,
-                         weight_decay=tc.weight_decay)
+        # the update program Miner runs: replayed updates must track the
+        # miner's own bits
+        _, self.update = inner_optimizer(self.spec.train_cfg)
 
     def process_epoch(self, plan: dict) -> None:
         S = self.spec.config
@@ -802,9 +797,8 @@ class ValidatorActor(ActorProcess):
                                                 g_out["shape"])
                         g_params, _ = sm.stage_backward(
                             params, x_in, g_out, self.model_spec, role)
-                    params, opt_state = self.opt.update(
+                    params, opt_state, inner_step = self.update(
                         g_params, opt_state, params, inner_step)
-                    inner_step = inner_step + 1
                     if ok:
                         validated += 1.0
                     done.add(tick)

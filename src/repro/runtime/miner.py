@@ -7,7 +7,8 @@ local work log that validators can replay bit-exactly.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, TYPE_CHECKING
+import functools
+from typing import Any, Callable, Optional, TYPE_CHECKING
 
 import jax
 from jax.flatten_util import ravel_pytree
@@ -22,6 +23,38 @@ from repro.runtime import stage_model as sm
 
 if TYPE_CHECKING:
     from repro.api.transport import Transport
+
+# Times the shared inner update (``inner_optimizer``) was traced, counted
+# inside its traced body: tests read it to tell one cached program per
+# stage's parameter shapes from a re-trace on every call.
+UPDATE_TRACES = 0
+
+
+@functools.lru_cache(maxsize=None)
+def _inner_optimizer(lr: float, warmup_steps: int, beta1: float,
+                     beta2: float, weight_decay: float):
+    opt = adamw(cosine_warmup(lr, warmup_steps, 10_000), beta1=beta1,
+                beta2=beta2, weight_decay=weight_decay)
+
+    def update(grads, opt_state, params, inner_step):
+        global UPDATE_TRACES
+        UPDATE_TRACES += 1
+        params, opt_state = opt.update(grads, opt_state, params, inner_step)
+        return params, opt_state, inner_step + 1
+
+    return opt.init, jax.jit(update, donate_argnums=(1, 2))
+
+
+def inner_optimizer(tc: TrainConfig) -> tuple[Callable, Callable]:
+    """``(init, update)`` of the swarm's inner AdamW at ``tc``'s
+    hyperparameters.  ``update(grads, opt_state, params, inner_step)``
+    returns ``(params, opt_state, inner_step + 1)`` from one jitted program
+    with the schedule and bias corrections inside, and donates
+    ``opt_state`` and ``params``: pass only arrays the caller owns and
+    drops.  One ``update`` serves every miner and every replay of the same
+    hyperparameters, so a validator's replay follows the miner's bits."""
+    return _inner_optimizer(tc.lr, tc.warmup_steps, tc.beta1, tc.beta2,
+                            tc.weight_decay)
 
 
 @dataclasses.dataclass
@@ -55,11 +88,9 @@ class Miner:
         self.role = spec.role(stage)
         self.transport = transport
         self.params = params
-        tc = train_cfg or TrainConfig(lr=1e-3, warmup_steps=20)
-        self.opt = adamw(cosine_warmup(tc.lr, tc.warmup_steps, 10_000),
-                         beta1=tc.beta1, beta2=tc.beta2,
-                         weight_decay=tc.weight_decay)
-        self.opt_state = self.opt.init(params)
+        init, self.update = inner_optimizer(
+            train_cfg or TrainConfig(lr=1e-3, warmup_steps=20))
+        self.opt_state = init(params)
         self.inner_step = jnp.zeros((), jnp.int32)
         self.batches_done = 0
         self.work_log: list[WorkItem] = []
@@ -101,10 +132,11 @@ class Miner:
             return g_x
 
     def _apply(self, grads) -> None:
-        with span("optimizer"):
-            self.params, self.opt_state = self.opt.update(
+        with span("optimizer") as s:
+            traces = UPDATE_TRACES
+            self.params, self.opt_state, self.inner_step = self.update(
                 grads, self.opt_state, self.params, self.inner_step)
-            self.inner_step = self.inner_step + 1
+            s.set_metadata(traced=UPDATE_TRACES - traces)
         self.batches_done += 1
         if self.work_log:
             self.work_log[-1].did_backward = True

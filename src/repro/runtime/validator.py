@@ -91,7 +91,6 @@ class Validator:
                 params, opt_state, inner_step = jax.device_put(
                     (snapshot["params"], snapshot["opt_state"],
                      snapshot["inner_step"]))
-            opt = miner.opt
             spec, role = miner.spec, miner.role
 
             checked = passed = 0
@@ -132,9 +131,10 @@ class Validator:
                                             g_out["shape"])
                     g_params, _ = sm.stage_backward(params, x_in, g_out,
                                                     spec, role)
-                params, opt_state = opt.update(g_params, opt_state, params,
-                                               inner_step)
-                inner_step = inner_step + 1
+                # the miner's own update program, donating the restored
+                # copies this replay owns
+                params, opt_state, inner_step = miner.update(
+                    g_params, opt_state, params, inner_step)
                 if ok:
                     validated_backwards += 1.0
 

@@ -164,6 +164,16 @@ def test_store_get_carries_the_entry_bytes(runs):
         == runs["epoch_downloaded"]
 
 
+def test_optimizer_span_counts_its_traces(runs):
+    """Each update's span says whether it traced the update program; the
+    untraced epoch before it built both stages' programs, so none did."""
+    opt = [e for e in runs["events"] if e[0] == "iota.optimizer"]
+    assert opt and all(e[4].get("traced") == 0 for e in opt), \
+        [e[4] for e in opt]
+    for e in opt:
+        assert _chain(e, runs["events"])[0].startswith("miner.backward")
+
+
 def _anchors(swarm) -> list:
     return [np.asarray(ravel_pytree(jax.tree.map(
         lambda x: x.astype(jnp.float32), a))[0]) for a in swarm.anchors]
